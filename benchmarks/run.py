@@ -15,6 +15,10 @@ def main() -> None:
     args = ap.parse_args()
     scale = 0.35 if args.quick else 1.0
 
+    from repro.runtime import enable_compile_cache
+
+    enable_compile_cache()
+
     from . import (bench_chaos, bench_embedding_traffic, bench_fig7_vary_k,
                    bench_fig8_subgraphs, bench_fig9_global_init,
                    bench_fig10_scalability, bench_kernels, bench_sketch,

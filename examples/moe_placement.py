@@ -16,7 +16,9 @@ from repro.configs import get_config
 from repro.core.moe_placement import alltoall_traffic, build_expert_placement
 from repro.models.model import build_model
 from repro.models.moe import apply_moe
+from repro.runtime import enable_compile_cache
 
+enable_compile_cache()
 cfg = get_config("deepseek-v2-236b").reduced(num_experts=16,
                                              num_experts_per_tok=4)
 model = build_model(cfg)

@@ -22,7 +22,9 @@ import numpy as np
 from repro.api import ParsaConfig, partition
 from repro.core import evaluate, improvement, random_parts
 from repro.graphs import text_like
+from repro.runtime import enable_compile_cache
 
+enable_compile_cache()
 k = 16
 print("building a documents × vocabulary bipartite graph ...")
 g = text_like(num_docs=2000, vocab=6000, mean_len=50, seed=0)

@@ -6,6 +6,7 @@ for any of the 10 architectures.
 import argparse
 
 from repro.launch import serve as serve_mod
+from repro.runtime import enable_compile_cache
 
 
 def main():
@@ -14,6 +15,7 @@ def main():
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--gen", type=int, default=24)
     args = ap.parse_args()
+    enable_compile_cache()
     serve_mod.main(["--arch", args.arch, "--reduce", "--batch",
                     str(args.batch), "--prompt-len", "12", "--gen",
                     str(args.gen)])

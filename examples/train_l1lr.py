@@ -10,6 +10,7 @@ from repro.api import ParsaConfig, partition
 from repro.core import random_parts
 from repro.graphs import ctr_like
 from repro.ml import DBPGConfig, PSCluster, make_problem
+from repro.runtime import enable_compile_cache
 
 
 def main():
@@ -19,6 +20,7 @@ def main():
     ap.add_argument("--rows", type=int, default=1200)
     ap.add_argument("--features", type=int, default=5000)
     args = ap.parse_args()
+    enable_compile_cache()
     k = args.k
 
     print("generating CTR-like training data ...")

@@ -11,6 +11,7 @@ import argparse
 import sys
 
 from repro.launch import train as train_mod
+from repro.runtime import enable_compile_cache
 
 
 def main():
@@ -20,6 +21,7 @@ def main():
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
     args = ap.parse_args()
+    enable_compile_cache()
     hist = train_mod.main([
         "--arch", args.arch, "--reduce", "--steps", str(args.steps),
         "--batch", str(args.batch), "--seq", str(args.seq),

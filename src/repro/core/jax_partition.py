@@ -721,8 +721,6 @@ def _parallel_scan_fn(devices, k: int, merge_every: int, use_kernel: bool,
     """
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from ..compat import shard_map
-
     axis = "parsa_workers"
     mesh = Mesh(np.asarray(devices), (axis,))
 
@@ -767,7 +765,7 @@ def _parallel_scan_fn(devices, k: int, merge_every: int, use_kernel: bool,
         pushed = jax.lax.psum(pushed, axis)
         return parts[None], s_masks, sizes, pushed
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis),) * 6 + (P(), P()),
         out_specs=(P(axis), P(), P(), P()),
@@ -896,11 +894,15 @@ def _run_parallel_packed_scan(
                            sketch)
     _count_dispatch(count_name,
                     nbytes=int(s_masks.nbytes) + int(sizes.nbytes),
-                    k=k, workers=workers, blocks=nb_per * workers)
+                    k=k, workers=workers, blocks=nb_per * workers,
+                    devices=[d.id for d in devices])
     parts_blocks, s_out, sizes_out, pushed_words = fn(
         shard(packed.valid), shard(packed.widx), shard(packed.vals),
         shard(packed.trunc), shard(packed.tr_ids), shard(packed.tr_masks),
         s_masks, sizes)
+    # where the per-worker outputs landed: one shard per mesh device
+    annotate_dispatch(shard_devices=sorted(
+        s.device.id for s in parts_blocks.addressable_shards))
     W = packed.tr_masks.shape[-1]
     n_super = nb_per // merge_every
     traffic = {

@@ -115,12 +115,6 @@ def packed_delta(new: np.ndarray, old: np.ndarray) -> np.ndarray:
     return new & ~old
 
 
-# per-byte popcount table: the numpy<2.0 fallback (np.bitwise_count is 2.0+)
-_POPCOUNT8 = np.unpackbits(
-    np.arange(256, dtype=np.uint8).reshape(-1, 1), axis=1).sum(
-        axis=1).astype(np.int64)
-
-
 def packed_intersect_counts(a: np.ndarray, b: np.ndarray) -> np.ndarray:
     """All-pairs intersection sizes of two packed bitmask stacks:
     ``out[i, j] = |rows_a[i] ∩ rows_b[j]|`` for (ka, W) × (kb, W) int32
@@ -137,9 +131,7 @@ def packed_intersect_counts(a: np.ndarray, b: np.ndarray) -> np.ndarray:
             f"packed stacks must share the word width, got {a.shape} "
             f"vs {b.shape}")
     inter = a[:, None, :] & b[None, :, :]
-    if hasattr(np, "bitwise_count"):
-        return np.bitwise_count(inter).sum(axis=-1, dtype=np.int64)
-    return _POPCOUNT8[inter.view(np.uint8)].sum(axis=-1)
+    return np.bitwise_count(inter).sum(axis=-1, dtype=np.int64)
 
 
 def packed_union_delta(
@@ -199,9 +191,9 @@ def refine_sweep_chunk(
     words_p = jnp.pad(tile_words, [(0, pk), (0, 0)])
     cost_p = jnp.pad(cost, [(0, pk)])
     parts, cost_out = refine_sweep_kernel(
-        words_p, prev.reshape(1, C), cost_p.reshape(1, k + pk),
+        words_p, prev.reshape(1, C), cost_p.reshape(k + pk, 1),
         interpret=interpret)
-    return cost_out[0, :k], parts[0]
+    return cost_out[:k, 0], parts[0]
 
 
 def _gather_row_cols(

@@ -33,8 +33,13 @@ VMEM budget per step (B=1024, bw=512, k≤64):
 This file also hosts the other fused lattice kernels of the pipeline:
 ``packed_union_delta_kernel`` (Alg 4 server merge wire ops) and
 ``refine_sweep_kernel`` (the Algorithm 2 cost-update sweep of one V chunk —
-bit tile, cost vector, and parts row all VMEM-resident; ≈ (k + 33·32)·cw·4
-bytes ≪ VMEM for cw=128 chunks at k≤64).
+packed need words, cost column, and parts row all VMEM-resident; ≈
+(k + 32)·cw·4 bytes ≪ VMEM for cw=128 chunks at k≤64).
+
+Mosaic lowers no dynamic slice or dynamic update of a vector, so a column
+or lane chosen at run time is always read as a one-hot select over an iota
+followed by a reduction (``tests/test_tpu_compile.py`` holds every kernel
+to that by compiling it for a described v5e).
 """
 from __future__ import annotations
 
@@ -48,12 +53,59 @@ from jax.experimental.pallas import tpu as pltpu
 from .ref import BIG
 
 
+def _select_reduce(cost, retired_ref, order_ref, enabled_ref,
+                   umin_ref, cmin_ref, *, greedy: bool):
+    """Reduce a VMEM-resident (B, k) cost tile to the two (1, k) outputs.
+
+    Shared by both select kernels.  Mosaic lowers no dynamic slice of a
+    vector, so every "column j" below is a one-hot select over a lane iota
+    followed by a lane reduction; with one hot lane the sum is exact.
+    """
+    B, k = cost.shape
+    ret = retired_ref[...]                               # (B, 1) int32 0/1
+    iota_b = jax.lax.broadcasted_iota(jnp.int32, (B, 1), 0)
+    if not greedy:
+        masked = jnp.where(ret != 0, BIG, cost)          # (B, k)
+        mins = jnp.min(masked, axis=0, keepdims=True)    # (1, k)
+        # first-occurrence argmin via the iota-min trick
+        hit = masked == mins
+        cmin_ref[...] = mins
+        umin_ref[...] = jnp.min(jnp.where(hit, iota_b, B), axis=0,
+                                keepdims=True)
+        return
+    order = order_ref[...]      # (1, k) int32
+    enabled = enabled_ref[...]  # (1, k) int32
+    iota_k = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
+
+    def pick(j, carry):
+        u_sel, c_sel, ret = carry                        # (1,k),(1,k),(B,1)
+        slot = iota_k == j
+        col = jnp.sum(jnp.where(slot, order, 0), axis=1, keepdims=True)
+        en = jnp.sum(jnp.where(slot, enabled, 0), axis=1, keepdims=True)
+        c = jnp.sum(jnp.where(iota_k == col, cost, 0), axis=1,
+                    keepdims=True)                       # (B, 1)
+        c = jnp.where(ret != 0, BIG, c)
+        m = jnp.min(c, axis=0, keepdims=True)            # (1, 1)
+        u = jnp.min(jnp.where(c == m, iota_b, B), axis=0,
+                    keepdims=True)                       # first min row
+        act = (en != 0) & (m < BIG)
+        ret = jnp.where((iota_b == u) & act, 1, ret)
+        u_sel = jnp.where(slot, jnp.where(act, u, -1), u_sel)
+        c_sel = jnp.where(slot, jnp.where(act, m, BIG), c_sel)
+        return u_sel, c_sel, ret
+
+    u0 = jnp.full((1, k), -1, jnp.int32)
+    c0 = jnp.full((1, k), BIG, jnp.int32)
+    u_sel, c_sel, _ = jax.lax.fori_loop(0, k, pick, (u0, c0, ret))
+    umin_ref[...] = u_sel
+    cmin_ref[...] = c_sel
+
+
 def _select_kernel(nbr_ref, s_ref, retired_ref, order_ref, enabled_ref,
                    umin_ref, cmin_ref, acc_ref, *, greedy: bool):
     w_idx = pl.program_id(0)
     nw = pl.num_programs(0)
     k = s_ref.shape[0]
-    B = nbr_ref.shape[0]
 
     @pl.when(w_idx == 0)
     def _init():
@@ -72,44 +124,8 @@ def _select_kernel(nbr_ref, s_ref, retired_ref, order_ref, enabled_ref,
 
     @pl.when(w_idx == nw - 1)
     def _reduce():
-        cost = acc_ref[...]                                  # (B, k)
-        ret = retired_ref[...] != 0                          # (B, 1)
-        iota_b = jax.lax.broadcasted_iota(jnp.int32, (B, 1), 0)
-        if not greedy:
-            masked = jnp.where(ret, BIG, cost)               # (B, k)
-            mins = jnp.min(masked, axis=0)                   # (k,)
-            # first-occurrence argmin via the iota-min trick
-            hit = masked == mins[None, :]
-            argmins = jnp.min(jnp.where(hit, iota_b, B), axis=0)
-            cmin_ref[...] = mins[None, :]
-            umin_ref[...] = argmins[None, :]
-        else:
-            order = order_ref[...]      # (1, k) int32
-            enabled = enabled_ref[...]  # (1, k) int32
-
-            def pick(j, carry):
-                u_sel, c_sel, ret = carry                    # (1,k),(1,k),(B,1)
-                col = jax.lax.dynamic_index_in_dim(
-                    order, j, 1, keepdims=False)[0]
-                c = jax.lax.dynamic_slice(cost, (0, col), (B, 1))
-                c = jnp.where(ret, BIG, c)                   # (B, 1)
-                m = jnp.min(c)
-                u = jnp.min(jnp.where(c == m, iota_b, B))    # first min row
-                en = jax.lax.dynamic_index_in_dim(
-                    enabled, j, 1, keepdims=False)[0] != 0
-                act = en & (m < BIG)
-                ret = ret | ((iota_b == u) & act)
-                iota_k = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
-                u_sel = jnp.where(iota_k == j, jnp.where(act, u, -1), u_sel)
-                c_sel = jnp.where(iota_k == j, jnp.where(act, m, BIG), c_sel)
-                return u_sel, c_sel, ret
-
-            u0 = jnp.full((1, k), -1, jnp.int32)
-            c0 = jnp.full((1, k), BIG, jnp.int32)
-            u_sel, c_sel, _ = jax.lax.fori_loop(0, k, pick, (u0, c0, ret),
-                                                unroll=True)
-            umin_ref[...] = u_sel
-            cmin_ref[...] = c_sel
+        _select_reduce(acc_ref[...], retired_ref, order_ref, enabled_ref,
+                       umin_ref, cmin_ref, greedy=greedy)
 
 
 def _sketch_select_kernel(nbr_ref, s_ref, retired_ref, order_ref, enabled_ref,
@@ -127,59 +143,26 @@ def _sketch_select_kernel(nbr_ref, s_ref, retired_ref, order_ref, enabled_ref,
     k = s_ref.shape[0]
     B = nbr_ref.shape[0]
     nbr = nbr_ref[...]  # (B, Ws) int32 — the entire sketched block tile
+    iota_k = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
 
     def accum(i, acc):
         s_row = s_ref[i, :]  # (Ws,) int32
         masked = nbr & ~s_row[None, :]
         partial = jax.lax.population_count(masked).astype(jnp.int32).sum(
-            axis=1)
-        return jax.lax.dynamic_update_slice(acc, partial[:, None], (0, i))
+            axis=1, keepdims=True)                       # (B, 1)
+        return jnp.where(iota_k == i, partial, acc)
 
     cost = jax.lax.fori_loop(0, k, accum,
                              jnp.zeros((B, k), jnp.int32), unroll=True)
-
-    ret = retired_ref[...] != 0                          # (B, 1)
-    iota_b = jax.lax.broadcasted_iota(jnp.int32, (B, 1), 0)
-    if not greedy:
-        masked = jnp.where(ret, BIG, cost)               # (B, k)
-        mins = jnp.min(masked, axis=0)                   # (k,)
-        hit = masked == mins[None, :]
-        argmins = jnp.min(jnp.where(hit, iota_b, B), axis=0)
-        cmin_ref[...] = mins[None, :]
-        umin_ref[...] = argmins[None, :]
-    else:
-        order = order_ref[...]      # (1, k) int32
-        enabled = enabled_ref[...]  # (1, k) int32
-
-        def pick(j, carry):
-            u_sel, c_sel, ret = carry                    # (1,k),(1,k),(B,1)
-            col = jax.lax.dynamic_index_in_dim(
-                order, j, 1, keepdims=False)[0]
-            c = jax.lax.dynamic_slice(cost, (0, col), (B, 1))
-            c = jnp.where(ret, BIG, c)                   # (B, 1)
-            m = jnp.min(c)
-            u = jnp.min(jnp.where(c == m, iota_b, B))    # first min row
-            en = jax.lax.dynamic_index_in_dim(
-                enabled, j, 1, keepdims=False)[0] != 0
-            act = en & (m < BIG)
-            ret = ret | ((iota_b == u) & act)
-            iota_k = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
-            u_sel = jnp.where(iota_k == j, jnp.where(act, u, -1), u_sel)
-            c_sel = jnp.where(iota_k == j, jnp.where(act, m, BIG), c_sel)
-            return u_sel, c_sel, ret
-
-        u0 = jnp.full((1, k), -1, jnp.int32)
-        c0 = jnp.full((1, k), BIG, jnp.int32)
-        u_sel, c_sel, _ = jax.lax.fori_loop(0, k, pick, (u0, c0, ret),
-                                            unroll=True)
-        umin_ref[...] = u_sel
-        cmin_ref[...] = c_sel
+    _select_reduce(cost, retired_ref, order_ref, enabled_ref,
+                   umin_ref, cmin_ref, greedy=greedy)
 
 
 # padded sketch widths beyond this many words exceed the VMEM budget of the
 # gridless kernel (B=1024 × 2048 × 4 B = 8 MiB for the nbr tile alone) —
 # wrappers must fall back to the W-gridded kernel above it
 SKETCH_KERNEL_MAX_WORDS = 2048
+_DEFAULT_SCOPED_VMEM = 16 << 20
 
 
 @functools.partial(jax.jit, static_argnames=("greedy", "interpret"))
@@ -200,12 +183,17 @@ def sketch_select_kernel(
         raise ValueError(
             f"sketch width {Ws} words exceeds the VMEM-resident budget "
             f"({SKETCH_KERNEL_MAX_WORDS}); use parsa_select_kernel")
+    # the whole (B, Ws) tile, one temporary of its size and 4 MiB for the
+    # rest: 20 MiB at the width guard with B=1024, above the 16 MiB default
+    # scoped-VMEM limit of a v5e core; smaller tiles keep that default
+    vmem = max(_DEFAULT_SCOPED_VMEM, 2 * B * Ws * 4 + (4 << 20))
     umin, cmin = pl.pallas_call(
         functools.partial(_sketch_select_kernel, greedy=greedy),
         out_shape=[
             jax.ShapeDtypeStruct((1, k), jnp.int32),
             jax.ShapeDtypeStruct((1, k), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem),
         interpret=interpret,
     )(nbr_masks, s_masks, retired, order, enabled)
     return umin, cmin
@@ -216,40 +204,44 @@ def _refine_sweep_kernel(words_ref, prev_ref, cost_ref,
     """Fused Algorithm 2 cost-update: sweep one V chunk entirely in VMEM.
 
     words (k, cw) int32 packed need bits; prev (1, C) int32 entering
-    assignments (C = 32·cw); cost (1, k) int32.  Emits (parts (1, C),
-    cost' (1, k)).  The (k, C) bit tile is expanded once from the packed
-    words and the C greedy steps run as a fori_loop over VMEM state — the
-    tile, the cost vector, and the growing parts row never leave the core.
+    assignments (C = 32·cw); cost (k, 1) int32, one partition per sublane.
+    Emits (parts (1, C), cost' (k, 1)).  The C greedy steps run as a
+    fori_loop over VMEM state — the words, the cost column, and the
+    growing parts row never leave the core.  Step j reads need column j
+    straight from its packed word: a one-hot lane select picks word j/32,
+    a shift picks bit j%32 (Mosaic lowers no dynamic slice of a vector).
     Bit-exact vs ``ref.refine_sweep_ref``.
     """
     k, cw = words_ref.shape
     C = cw * 32
     words = words_ref[...]
-    shifts = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 32), 2)
-    bits = ((words[:, :, None] >> shifts) & 1).reshape(k, C)   # (k, C)
-    nneed = bits.sum(axis=0, dtype=jnp.int32).reshape(1, C)
     prev = prev_ref[...]
-    iota_k = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
+    iota_w = jax.lax.broadcasted_iota(jnp.int32, (1, cw), 1)
     iota_kc = jax.lax.broadcasted_iota(jnp.int32, (k, 1), 0)
     iota_c = jax.lax.broadcasted_iota(jnp.int32, (1, C), 1)
 
     def step(j, carry):
-        cost, parts = carry                                    # (1,k), (1,C)
-        bcol = jax.lax.dynamic_slice(bits, (0, j), (k, 1))     # (k, 1)
-        nj = jax.lax.dynamic_slice(nneed, (0, j), (1, 1))[0, 0]
-        cur = jax.lax.dynamic_slice(prev, (0, j), (1, 1))[0, 0]
+        cost, parts = carry                                    # (k,1), (1,C)
+        word = jnp.sum(jnp.where(iota_w == j // 32, words, 0), axis=1,
+                       keepdims=True)                          # (k, 1)
+        bcol = (word >> (j % 32)) & 1                          # (k, 1)
+        nj = jnp.sum(bcol, axis=0, keepdims=True)              # (1, 1)
+        at_j = iota_c == j
+        cur = jnp.sum(jnp.where(at_j, prev, 0), axis=1, keepdims=True)
         # retract j's old contribution: cost_cur −= −1 + (n_j − u_{cur,j})
-        bitc = jnp.sum(jnp.where(iota_kc == cur, bcol, 0))
+        bitc = jnp.sum(jnp.where(iota_kc == cur, bcol, 0), axis=0,
+                       keepdims=True)
         retract = jnp.where(cur >= 0, 1 - nj + bitc, 0)
-        cost = cost + jnp.where(iota_k == cur, retract, 0)
+        cost = cost + jnp.where(iota_kc == cur, retract, 0)
         # pick the needing partition with minimum cost (first on ties)
-        masked = jnp.where(jnp.transpose(bcol) > 0, cost, BIG)  # (1, k)
-        m = jnp.min(masked)
-        xi = jnp.min(jnp.where(masked == m, iota_k, k))
+        masked = jnp.where(bcol > 0, cost, BIG)                # (k, 1)
+        m = jnp.min(masked, axis=0, keepdims=True)
+        xi = jnp.min(jnp.where(masked == m, iota_kc, k), axis=0,
+                     keepdims=True)
         act = nj > 0
         # line 8: cost_ξ += −1 + (n_j − 1)
-        cost = cost + jnp.where((iota_k == xi) & act, nj - 2, 0)
-        parts = jnp.where(iota_c == j, jnp.where(act, xi, -1), parts)
+        cost = cost + jnp.where((iota_kc == xi) & act, nj - 2, 0)
+        parts = jnp.where(at_j, jnp.where(act, xi, -1), parts)
         return cost, parts
 
     cost0 = cost_ref[...]
@@ -263,18 +255,18 @@ def _refine_sweep_kernel(words_ref, prev_ref, cost_ref,
 def refine_sweep_kernel(
     tile_words: jax.Array,  # (k, cw) int32, k % 8 == 0
     prev: jax.Array,        # (1, C) int32, C == 32·cw
-    cost: jax.Array,        # (1, k) int32
+    cost: jax.Array,        # (k, 1) int32
     *,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
-    """Returns (parts (1, C), cost' (1, k)) int32 — see ``_refine_sweep_kernel``."""
+    """Returns (parts (1, C), cost' (k, 1)) int32 — see ``_refine_sweep_kernel``."""
     k, cw = tile_words.shape
     C = cw * 32
     parts, cost_out = pl.pallas_call(
         _refine_sweep_kernel,
         out_shape=[
             jax.ShapeDtypeStruct((1, C), jnp.int32),
-            jax.ShapeDtypeStruct((1, k), jnp.int32),
+            jax.ShapeDtypeStruct((k, 1), jnp.int32),
         ],
         interpret=interpret,
     )(tile_words, prev, cost)

@@ -11,6 +11,7 @@ device state (the dry-run sets XLA_FLAGS before any jax import).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 HW = {
     "peak_flops_bf16": 197e12,   # per chip
@@ -25,10 +26,16 @@ def make_production_mesh(*, multi_pod: bool = False):
     if override:
         shape = tuple(int(x) for x in override.split(","))
         axes = ("pod", "data", "model")[-len(shape):]
-        return jax.make_mesh(shape, axes)
+        return _auto_mesh(shape, axes)
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
+
+
+def _auto_mesh(shape: tuple, axes: tuple):
+    """``jax.make_mesh`` with Auto axes: the model code places activations
+    with ``with_sharding_constraint``, which refuses Explicit axes."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def mesh_name(mesh) -> str:
@@ -37,7 +44,7 @@ def mesh_name(mesh) -> str:
 
 def make_host_mesh():
     """Degenerate 1-device mesh for smoke tests."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))
 
 
 def dp_axes(mesh) -> tuple:

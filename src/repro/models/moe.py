@@ -28,9 +28,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-from ..compat import shard_map
 
 from .layers import _dense_init
 from .shardctx import constrain, current_rules

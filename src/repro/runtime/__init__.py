@@ -1,3 +1,4 @@
+from .compile_cache import REPO_CACHE_DIR, enable_compile_cache  # noqa: F401
 from .fault import (  # noqa: F401
     CircuitBreaker,
     FaultConfig,

@@ -684,7 +684,8 @@ class ServingEngine:
             pull_inter_bytes=handle.inter_bytes,
             push_inter_bytes=stats.get("push_inter_bytes", 0),
             warmup=t < self.warmup,
-            queue_s=queue, modeled_s=modeled))
+            queue_s=queue, modeled_s=modeled,
+            loss=stats.get("loss", float("nan"))))
         observe = getattr(src, "observe_request", None)
         if observe is not None:
             observe(req, handle, modeled, measured)

@@ -165,6 +165,7 @@ class RequestRecord:
     warmup: bool = False      # excluded from the summary statistics
     queue_s: float = 0.0      # NIC-backlog delay ahead of the transfer
     modeled_s: float = 0.0    # deterministic virtual-clock latency
+    loss: float = float("nan")  # the served step's loss, where it has one
 
 
 class LatencyRecorder:

@@ -69,11 +69,6 @@ _MIX1 = np.uint64(0xBF58476D1CE4E5B9)
 _MIX2 = np.uint64(0x94D049BB133111EB)
 _GOLDEN = np.uint64(0x9E3779B97F4A7C15)
 
-# per-byte popcount (numpy < 2.0 has no np.bitwise_count)
-_POPCOUNT8 = np.unpackbits(
-    np.arange(256, dtype=np.uint8).reshape(-1, 1), axis=1).sum(
-        axis=1).astype(np.int64)
-
 _MAP_CHUNK = 1 << 24  # columns mapped per host pass (bounds transients)
 
 
@@ -91,9 +86,7 @@ def _splitmix64(x: np.ndarray) -> np.ndarray:
 def packed_popcount_rows(masks: np.ndarray) -> np.ndarray:
     """Per-row popcount of a packed (rows, W) bitmask stack → (rows,) int64."""
     m = np.ascontiguousarray(masks).view(np.uint32)
-    if hasattr(np, "bitwise_count"):
-        return np.bitwise_count(m).sum(axis=-1, dtype=np.int64)
-    return _POPCOUNT8[m.view(np.uint8).reshape(m.shape[0], -1)].sum(axis=-1)
+    return np.bitwise_count(m).sum(axis=-1, dtype=np.int64)
 
 
 def linear_counting_estimate(occupied: int, m: int) -> float:

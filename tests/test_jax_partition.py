@@ -437,16 +437,14 @@ def test_shard_parsa_step_single_device():
     """One Alg-4 round through shard_map on a 1-wide data axis."""
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from repro.compat import shard_map
-
     g = text_like(256, 400, mean_len=12, seed=8)
     k, block = 4, 64
     packed = pack_graph_blocks(g, block)
     body = shard_parsa_step(k, axis="data", use_kernel=False)
     mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
     W = (g.num_v + 31) // 32
-    fn = shard_map(body, mesh=mesh, in_specs=(P(),) * 8,
-                   out_specs=(P(), P(), P()), check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(),) * 8,
+                       out_specs=(P(), P(), P()), check_vma=False)
     parts, merged, sizes = fn(
         jnp.asarray(packed.valid), jnp.asarray(packed.widx),
         jnp.asarray(packed.vals), jnp.asarray(packed.trunc),
@@ -471,16 +469,14 @@ def test_shard_parsa_step_padded_blocks(select):
     """Ragged U-shards: padding rows must not leak into sizes or S."""
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from repro.compat import shard_map
-
     g = text_like(150, 300, mean_len=10, seed=3)  # 150 % 64 != 0 → padding
     k, block = 4, 64
     packed = pack_graph_blocks(g, block)
     body = shard_parsa_step(k, axis="data", use_kernel=False, select=select)
     mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
     W = (g.num_v + 31) // 32
-    fn = shard_map(body, mesh=mesh, in_specs=(P(),) * 8,
-                   out_specs=(P(), P(), P()), check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(),) * 8,
+                       out_specs=(P(), P(), P()), check_vma=False)
     parts, merged, sizes = fn(
         jnp.asarray(packed.valid), jnp.asarray(packed.widx),
         jnp.asarray(packed.vals), jnp.asarray(packed.trunc),

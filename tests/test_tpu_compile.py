@@ -1,0 +1,112 @@
+"""Compile the Pallas kernels of the partition path, and the kernel-path
+scan, for a described TPU v5e at deployment widths — no chip needed.
+
+Interpret mode accepts what Mosaic refuses (dynamic slices of vectors,
+scoped-VMEM overflow), so only these compiles show that the kernels run on
+the chip.  The topology is described inside a fixture, never at import:
+one process at a time may load the TPU compiler's library, so run this
+module on one worker (``pytest -n N --dist loadfile`` keeps a file on one
+worker).  The fixture skips only where libtpu is not installed; any other
+failure to describe the chip fails the tests.
+"""
+from __future__ import annotations
+
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.core.jax_partition import _partition_scan
+from repro.kernels.parsa_cost.parsa_cost import parsa_cost_kernel
+from repro.kernels.parsa_cost.select import (
+    SKETCH_KERNEL_MAX_WORDS,
+    packed_union_delta_kernel,
+    parsa_select_kernel,
+    refine_sweep_kernel,
+    sketch_select_kernel,
+)
+
+W = 131072   # packed words of 2^22 hashed features
+B = 1024
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    if importlib.util.find_spec("libtpu") is None:
+        pytest.skip("no TPU compiler (libtpu) in this installation")
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # with libtpu present, a failure to describe the chip is a failure
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _i32(shape, sharding, dtype=jnp.int32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compiled_kernel(fn, *args) -> str:
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+@pytest.mark.parametrize("k,greedy", [(16, True), (64, True), (16, False)])
+def test_parsa_select_kernel_compiles(one_chip, k, greedy):
+    _compiled_kernel(
+        lambda *a: parsa_select_kernel(*a, greedy=greedy, bw=512),
+        _i32((B, W), one_chip), _i32((k, W), one_chip),
+        _i32((B, 1), one_chip), _i32((1, k), one_chip),
+        _i32((1, k), one_chip))
+
+
+def test_sketch_select_kernel_compiles_at_width_guard(one_chip):
+    ws, k = SKETCH_KERNEL_MAX_WORDS, 16
+    _compiled_kernel(
+        lambda *a: sketch_select_kernel(*a, greedy=True),
+        _i32((B, ws), one_chip), _i32((k, ws), one_chip),
+        _i32((B, 1), one_chip), _i32((1, k), one_chip),
+        _i32((1, k), one_chip))
+
+
+@pytest.mark.parametrize("chunk", [1024, 4096])
+def test_refine_sweep_kernel_compiles(one_chip, chunk):
+    k = 16
+    _compiled_kernel(refine_sweep_kernel, _i32((k, chunk // 32), one_chip),
+                     _i32((1, chunk), one_chip), _i32((k, 1), one_chip))
+
+
+def test_parsa_cost_kernel_compiles(one_chip):
+    _compiled_kernel(lambda a, b: parsa_cost_kernel(a, b),
+                     _i32((B, W), one_chip), _i32((16, W), one_chip))
+
+
+def test_packed_union_delta_kernel_compiles(one_chip):
+    _compiled_kernel(lambda a, b: packed_union_delta_kernel(a, b),
+                     _i32((16, W), one_chip), _i32((16, W), one_chip))
+
+
+def test_kernel_path_partition_scan_compiles(one_chip):
+    """A few blocks of the whole ``device_scan`` program, fused select
+    kernel inside the block scan, at the deployment's packed width."""
+    nb, b, cap, tb, k = 4, 256, 48, 1, 16
+    args = (_i32((nb, b), one_chip, jnp.bool_), _i32((nb, b, cap), one_chip),
+            _i32((nb, b, cap), one_chip), _i32((nb, b), one_chip, jnp.bool_),
+            _i32((nb, tb), one_chip), _i32((nb, tb, W), one_chip),
+            _i32((k, W), one_chip), _i32((k,), one_chip))
+    text = _partition_scan.lower(*args, k=k, use_kernel=True,
+                                 interpret=False).compile().as_text()
+    assert "tpu_custom_call" in text
