@@ -1,4 +1,5 @@
-"""repro.obs — virtual-clock tracing, flight recorder, metrics export.
+"""repro.obs — virtual-clock tracing, flight recorder, metrics export,
+and ``phase``, the host phase timer on the profiler's clock.
 
 One ``Observability`` object bundles the two sinks and threads through
 the pipeline as the single ``obs=`` hook (``ServingConfig.obs``,
@@ -13,7 +14,7 @@ from __future__ import annotations
 import pathlib
 
 from .trace import (Span, SpanHandle, Tracer, annotate_last_instant,
-                    dispatch_instant, trace_instant)
+                    dispatch_instant, phase, trace_instant)
 from .recorder import (CAUSE_KINDS, Explanation, FlightRecorder, ObsEvent)
 from .export import (chrome_trace_json, prometheus_text,
                      save_chrome_trace, to_chrome_trace)
@@ -21,7 +22,7 @@ from .export import (chrome_trace_json, prometheus_text,
 __all__ = [
     "Observability",
     "Span", "SpanHandle", "Tracer", "trace_instant", "dispatch_instant",
-    "annotate_last_instant",
+    "annotate_last_instant", "phase",
     "ObsEvent", "Explanation", "FlightRecorder", "CAUSE_KINDS",
     "to_chrome_trace", "chrome_trace_json", "save_chrome_trace",
     "prometheus_text",

@@ -17,8 +17,15 @@ Span trees emitted by the instrumented layers:
 
   * ``request → pull(wire/retry/queue)/compute/push`` — built by
     ``ServingEngine`` from the ``PullHandle``'s modeled breakdown;
-  * ``feed → pack/scan/merge/metrics`` — ``StreamSession.feed``;
+  * ``feed → prepare/pack/upload/launch/wait/append/metrics/release``
+    — ``StreamSession.feed``, one child per measured ``phase``;
   * ``elastic_op → plan/scan/migrate`` — ``ElasticSession`` ops.
+
+``phase`` is the one real-clock hook: it times a host phase with
+``time.perf_counter`` into the caller's ``timings`` dict and marks the
+same interval as a span the caller names in the profiler's trace
+(``jax.profiler.TraceAnnotation``), where it shares a clock with the
+device planes.
 
 Trace/span ids are plain ordinals (deterministic).  Context propagates
 two ways: explicitly (a ``SpanHandle`` adds children at offsets inside
@@ -36,10 +43,11 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import time
 from collections import deque
 
 __all__ = ["Span", "SpanHandle", "Tracer", "trace_instant",
-           "dispatch_instant", "annotate_last_instant"]
+           "dispatch_instant", "annotate_last_instant", "phase"]
 
 # Tracers currently installed (engine runs, `with tracer.installed()`);
 # module-level like jax_partition's _ACTIVE_COUNTERS so layers without an
@@ -209,3 +217,33 @@ def annotate_last_instant(**attrs) -> None:
     for t in _ACTIVE:
         if t.spans and t.spans[-1].name.startswith("dispatch:"):
             t.spans[-1].attrs.update(attrs)
+
+
+class phase:
+    """Time one host phase into ``timings[name]`` (``perf_counter``
+    seconds, always on) and mark the same interval as the profiler span
+    ``span`` carrying ``attrs``; the caller names the span.
+
+    With no profiler active the span is a no-op TraceMe; with one, it
+    lands on the host plane, on the device planes' clock.  ``with`` gives
+    the annotation: ``set_metadata(**counts)`` attaches counts known only
+    at the phase's end.  Adds no device synchronisation.  A class rather
+    than a generator so that back-to-back phases leave next to no
+    untimed host work between them."""
+
+    __slots__ = ("timings", "name", "annotation", "t0")
+
+    def __init__(self, timings: dict, name: str, *, span: str, **attrs):
+        self.t0 = time.perf_counter()
+        from jax.profiler import TraceAnnotation
+
+        self.timings = timings
+        self.name = name
+        self.annotation = TraceAnnotation(span, **attrs)
+
+    def __enter__(self):
+        return self.annotation.__enter__()
+
+    def __exit__(self, *exc):
+        self.annotation.__exit__(*exc)
+        self.timings[self.name] = time.perf_counter() - self.t0

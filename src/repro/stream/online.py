@@ -55,6 +55,7 @@ from ..core.jax_partition import (
 )
 from ..core.parallel import global_initialization
 from ..kernels.parsa_cost import coerce_packed_sets
+from ..obs import phase
 from .arena import StreamArena
 from .drift import DriftDecision, DriftTracker
 from .migrate import MigrationPlan, plan_migration
@@ -127,8 +128,25 @@ class StreamUpdate:
     repartitioned: bool
     migration: MigrationPlan | None  # set when this feed triggered repair
     traffic: TrafficCounters | None  # parallel feeds: push/pull this feed
-    timings: dict[str, float]
+    timings: dict[str, float]       # host seconds per phase (see feed)
     dispatches: dict[str, int]      # device launches issued by this feed
+    counters: dict[str, int]        # what the packed blocks hold and cost
+
+
+# timings keys that sum phases rather than name one
+_PHASE_SUMS = ("partition_u", "total")
+
+
+def _packed_counters(packed) -> dict[str, int]:
+    """Per-feed counts of the packed blocks a scan is given: the bytes of
+    the six arrays put on the device, and the truncated-row channel's
+    used and total slots (``tr_ids == B`` marks an empty slot)."""
+    B = packed.valid.shape[1]
+    arrays = (packed.valid, packed.widx, packed.vals, packed.trunc,
+              packed.tr_ids, packed.tr_masks)
+    return {"upload_bytes": sum(int(x.nbytes) for x in arrays),
+            "channel_rows": int((packed.tr_ids != B).sum()),
+            "channel_slots": int(packed.tr_ids.size)}
 
 
 class StreamSession:
@@ -209,105 +227,127 @@ class StreamSession:
         server sets are donated into the dispatch itself — a failure
         *inside* the launch remains unrecoverable, like any donated-carry
         jax program.
+
+        Every host step falls in one ``repro.obs.phase``, timed into
+        ``timings`` and marked as the profiler span ``parsa.feed.<phase>``
+        (``feed=`` the ordinal): ``prepare``, ``pack``, ``upload``,
+        ``launch``, ``wait`` (the host blocked on the scan), ``append``,
+        ``metrics``, ``repartition`` when drift fires, and ``release``
+        (the packed blocks' host memory freed).  Alg 4 feeds
+        time ``scan`` in place of upload/launch/wait.  ``partition_u`` is
+        the sum from upload to append; ``counters`` are
+        ``_packed_counters``'s, also attributes of the ``pack`` span.
         """
         import jax.numpy as jnp
 
         from ..core.jax_partition import dispatch_counter
 
         base = self.config.base
+        ordinal = self.n_feeds   # shared by every span of this feed
         timings: dict[str, float] = {}
+
+        def step(name: str) -> phase:
+            return phase(timings, name, span=f"parsa.feed.{name}",
+                         feed=ordinal)
+
         t_total = time.perf_counter()
         with dispatch_counter() as counts:
             n = chunk.num_u
-            if self.sketch is not None:
-                # host column remap only — the scan below stays one dispatch
-                self._true_num_v = max(self._true_num_v, chunk.num_v)
-                chunk = self.sketch.sketch_graph(chunk)
-            self.arena.prepare(chunk)   # validate + capacity growth only
-            order = self._rng.permutation(n)
-            t0 = time.perf_counter()
-            packed = pack_graph_blocks(
-                self.arena.capacity_graph(chunk), base.block_size,
-                order=order, cap=base.cap, tb_pad=self.config.tb_pad)
-            timings["pack"] = time.perf_counter() - t0
+            with step("prepare"):
+                if self.sketch is not None:
+                    # host column remap only; the scan stays one dispatch
+                    self._true_num_v = max(self._true_num_v, chunk.num_v)
+                    chunk = self.sketch.sketch_graph(chunk)
+                self.arena.prepare(chunk)  # validate + capacity growth only
+                order = self._rng.permutation(n)
+            with step("pack") as span:
+                packed = pack_graph_blocks(
+                    self.arena.capacity_graph(chunk), base.block_size,
+                    order=order, cap=base.cap, tb_pad=self.config.tb_pad)
+                counters = _packed_counters(packed)
+                span.set_metadata(**counters)
 
-            t0 = time.perf_counter()
             traffic = None
             if self.config.workers == 1:
-                _count_dispatch(
-                    "stream_feed_scan",
-                    nbytes=(int(self.arena.s_masks.nbytes)
-                            + int(self.arena.sizes.nbytes)),
-                    k=self.k)
-                parts_blocks, s_out, sz_out = _partition_scan(
-                    jnp.asarray(packed.valid), jnp.asarray(packed.widx),
-                    jnp.asarray(packed.vals), jnp.asarray(packed.trunc),
-                    jnp.asarray(packed.tr_ids), jnp.asarray(packed.tr_masks),
-                    self.arena.s_masks, self.arena.sizes,
-                    k=self.k, use_kernel=base.use_kernel,
-                    interpret=base.interpret,
-                    sketch=self.sketch is not None)
-                flat = np.asarray(parts_blocks).reshape(-1)[:n]
+                with step("upload"):
+                    _count_dispatch(
+                        "stream_feed_scan",
+                        nbytes=(int(self.arena.s_masks.nbytes)
+                                + int(self.arena.sizes.nbytes)),
+                        k=self.k)
+                    blocks = (
+                        jnp.asarray(packed.valid), jnp.asarray(packed.widx),
+                        jnp.asarray(packed.vals), jnp.asarray(packed.trunc),
+                        jnp.asarray(packed.tr_ids),
+                        jnp.asarray(packed.tr_masks))
+                with step("launch"):
+                    parts_blocks, s_out, sz_out = _partition_scan(
+                        *blocks, self.arena.s_masks, self.arena.sizes,
+                        k=self.k, use_kernel=base.use_kernel,
+                        interpret=base.interpret,
+                        sketch=self.sketch is not None)
+                with step("wait"):
+                    flat = np.asarray(parts_blocks).reshape(-1)[:n]
             else:
-                flat, s_out, sz_out, traffic = self._feed_parallel(
-                    packed, n, worker_weights)
-            # scan succeeded — commit: live sets, CSR append, parts
-            self.arena.s_masks, self.arena.sizes = s_out, sz_out
-            u_start, u_stop = self.arena.append(chunk)
-            parts_chunk = np.empty(n, np.int32)
-            parts_chunk[order] = flat
-            self._store_parts(u_start, parts_chunk)
-            timings["partition_u"] = time.perf_counter() - t0
-
-            t0 = time.perf_counter()
-            metrics = self._popcount_metrics()
-            timings["metrics"] = time.perf_counter() - t0
+                # upload, launch and wait in one: the Alg 4 runner is
+                # shared with the one-shot facade
+                with step("scan"):
+                    flat, s_out, sz_out, traffic = self._feed_parallel(
+                        packed, n, worker_weights)
+            with step("append"):
+                # scan succeeded — commit: live sets, CSR append, parts
+                self.arena.s_masks, self.arena.sizes = s_out, sz_out
+                u_start, u_stop = self.arena.append(chunk)
+                parts_chunk = np.empty(n, np.int32)
+                parts_chunk[order] = flat
+                self._store_parts(u_start, parts_chunk)
+            timings["partition_u"] = sum(
+                timings.get(name, 0.0)
+                for name in ("upload", "launch", "wait", "scan", "append"))
 
             decision = migration = None
-            if self.config.repartition == "drift":
-                decision = self.tracker.update(metrics)
-                if decision.repartition:
-                    t0 = time.perf_counter()
+            with step("metrics"):
+                metrics = self._popcount_metrics()
+                if self.config.repartition == "drift":
+                    decision = self.tracker.update(metrics)
+            if decision is not None and decision.repartition:
+                with step("repartition"):
                     migration = self.repartition()
-                    timings["repartition"] = time.perf_counter() - t0
                     metrics = self._popcount_metrics()
+            with step("release"):
+                # free the packed blocks (up to GBs of truncation channel)
+                # on the feed's clock rather than untimed at its return
+                packed = blocks = None
         self.n_feeds += 1
         timings["total"] = time.perf_counter() - t_total
         dispatches = {name: c for name, c in counts.items() if c}
         if self.obs is not None:
-            self._trace_feed(n, u_start, u_stop, timings,
-                             repartitioned=migration is not None)
+            self._trace_feed(n, u_start, u_stop, timings)
         return StreamUpdate(
-            chunk=self.n_feeds - 1, u_start=u_start, u_stop=u_stop,
+            chunk=ordinal, u_start=u_start, u_stop=u_stop,
             parts=self.parts[u_start:u_stop].copy(), metrics=metrics,
             drift=decision, repartitioned=migration is not None,
             migration=migration, traffic=traffic, timings=timings,
-            dispatches=dispatches)
+            dispatches=dispatches, counters=counters)
 
     def _trace_feed(self, n: int, u_start: int, u_stop: int,
-                    timings: dict, repartitioned: bool) -> None:
-        """Emit the ``feed → pack/scan(/merge)/metrics`` span tree.
+                    timings: dict) -> None:
+        """Emit the ``feed → <phase>...`` span tree, one child per phase
+        ``feed`` timed, in order.
 
         A feed has no modeled duration (it is host work, not a priced
-        transfer), so the span occupies one fixed virtual unit with
-        children at fixed fractions — deterministic across replays — and
-        the measured phase seconds attached as ``wall_s`` evidence."""
+        transfer), so the span occupies one fixed virtual unit shared
+        equally by its children — deterministic across replays — and each
+        phase's measured seconds ride along as ``wall_s`` evidence."""
         tr = self.obs.tracer
         sp = tr.begin("feed", v_start=tr.now, v_dur=1.0, track="stream",
                       feed=self.n_feeds - 1, rows=n, u_start=u_start,
-                      u_stop=u_stop, k=self.k,
+                      u_stop=u_stop, k=self.k, workers=self.config.workers,
                       wall_s=timings.get("total"))
-        sp.child("pack", 0.0, 0.25, wall_s=timings.get("pack"))
-        sp.child("scan", 0.25, 0.45, wall_s=timings.get("partition_u"),
-                 workers=self.config.workers)
-        if self.config.workers > 1:
-            # the all_gather + OR union-push folded into the parallel scan
-            sp.child("merge", 0.7, 0.1,
-                     merge_every=self.config.base.merge_every)
-        sp.child("metrics", 0.8, 0.1, wall_s=timings.get("metrics"))
-        if repartitioned:
-            sp.child("repartition", 0.9, 0.1,
-                     wall_s=timings.get("repartition"))
+        phases = [name for name in timings if name not in _PHASE_SUMS]
+        share = 1.0 / len(phases)
+        for i, name in enumerate(phases):
+            sp.child(name, i * share, share, wall_s=timings[name])
         tr.advance(1.0)
 
     def _feed_parallel(self, packed, n: int,

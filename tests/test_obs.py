@@ -220,16 +220,25 @@ def test_stream_feed_and_elastic_op_spans():
     sess = ElasticSession(ElasticConfig(stream=scfg, min_k=2, max_k=K + 2),
                           num_v=g.num_v, obs=obs)
     assert sess.stream.obs is obs          # one hook covers the stack
-    sess.feed(g.slice_u(0, 400))
-    sess.feed(g.slice_u(400, 800))
+    updates = [sess.feed(g.slice_u(0, 400)), sess.feed(g.slice_u(400, 800))]
     feeds = [sp for sp in obs.tracer.spans if sp.name == "feed"]
     assert len(feeds) == 2
     # the virtual clock advances one unit per feed
     assert feeds[1].v_start == pytest.approx(feeds[0].v_start + 1.0)
-    for f in feeds:
-        kids = {sp.name for sp in obs.tracer.spans
-                if sp.parent_id == f.span_id}
-        assert {"pack", "scan", "metrics"} <= kids
+    for f, upd in zip(feeds, updates):
+        kids = [sp for sp in obs.tracer.spans if sp.parent_id == f.span_id]
+        # one child per measured phase, in order, at equal virtual shares
+        phases = [name for name in upd.timings
+                  if name not in ("partition_u", "total")]
+        assert [sp.name for sp in kids] == phases
+        assert {"pack", "upload", "wait", "metrics"} <= set(phases)
+        assert [sp.wall_s for sp in kids] == [upd.timings[p]
+                                              for p in phases]
+        share = 1.0 / len(phases)
+        assert [sp.v_start - f.v_start for sp in kids] == pytest.approx(
+            [i * share for i in range(len(phases))])
+        assert all(sp.v_dur == pytest.approx(share) for sp in kids)
+        assert f.wall_s == upd.timings["total"]
 
     op = sess.repair(int(np.argmax(np.bincount(sess.parts, minlength=K))),
                      mode="warm")
@@ -240,6 +249,32 @@ def test_stream_feed_and_elastic_op_spans():
     kids = {sp.name for sp in obs.tracer.spans
             if sp.parent_id == ops[-1].span_id}
     assert kids == {"plan", "scan", "migrate"}
+
+
+def test_phase_times_under_the_callers_span(tmp_path):
+    """``phase`` writes its seconds to ``timings`` and marks the span the
+    caller names, with the attributes given at entry and at the end."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from repro.obs import phase
+
+    timings = {}
+    with jax.profiler.trace(str(tmp_path)):
+        with phase(timings, "step", span="caller.step", job=7) as ann:
+            ann.set_metadata(items=3)
+            time.sleep(0.002)
+    assert list(timings) == ["step"] and timings["step"] >= 0.002
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    stats = [dict(ev.stats)
+             for plane in ProfileData.from_file(path[-1]).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for ev in line.events
+             if ev.name == "caller.step"]
+    assert len(stats) == 1
+    assert (stats[0]["job"], stats[0]["items"]) == (7, 3)
 
 
 # --------------------------------------------------- explain() unit tests
