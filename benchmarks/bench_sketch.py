@@ -59,20 +59,6 @@ def _host_ram_bytes() -> int:
         return 64 << 30
 
 
-def _exact_pipeline_bytes(num_u: int, num_v: int, k: int, block: int,
-                          workers: int = 1) -> int:
-    """What the EXACT pipeline would have to allocate at (num_u, num_v):
-    the width-dependent set structures per worker (stale S copies, gather
-    buffer, rebuilt block tiles) plus the pack-time truncation side channel
-    ``tr_masks`` — a dense (n_blocks, TB, W) array carried through the scan
-    (host copy + device copy), which is what actually explodes first at
-    10^8 features."""
-    W = (num_v + 31) // 32
-    n_blocks = -(-num_u // block)
-    tr_bytes = 2 * n_blocks * W * 4          # TB >= 1; host + device copies
-    return set_structure_bytes(num_v, k, block, workers=workers) + tr_bytes
-
-
 def _true_score(graph, parts_u, k):
     """traffic_max of ``parts_u`` scored on the TRUE (unsketched) graph —
     the only honest way to compare exact- and sketch-mode partitions."""
@@ -153,12 +139,11 @@ def bench_acceptance(rows, num_u=1_000_000, k=16):
     # The exact path is skipped as unallocatable at this scale.  The gate
     # is the repo's own deployment config — the 8-worker parallel backend
     # bench_fig10 scales — where every worker rebuilds its (B, W) block
-    # tiles and holds stale S copies at full width, plus the pack-time
-    # truncation side channel; at W = 3.125e6 words that is ~217 GiB of
-    # live arrays before a single scan step runs.
+    # tiles and holds stale S copies at full width; at W = 3.125e6 words
+    # that is ~194 GiB of live arrays before a single scan step runs.
     ram = _host_ram_bytes()
-    exact_deploy = _exact_pipeline_bytes(num_u, num_v, k, 1024, workers=8)
-    exact_1w = _exact_pipeline_bytes(num_u, num_v, k, 1024, workers=1)
+    exact_deploy = set_structure_bytes(num_v, k, 1024, workers=8)
+    exact_1w = set_structure_bytes(num_v, k, 1024, workers=1)
     if exact_deploy > ram:
         print(f"# exact path SKIPPED as unallocatable: "
               f"{exact_deploy / 2**30:.0f} GiB live arrays at 8 workers "

@@ -183,7 +183,7 @@ def kernel_scan_hlo(num_u: int, num_v: int, k: int,
 
     return _partition_scan.lower(
         shape(nb, b, dtype=jnp.bool_), shape(nb, b, cap), shape(nb, b, cap),
-        shape(nb, b, dtype=jnp.bool_), shape(nb, 1), shape(nb, 1, words),
+        shape(nb, b, dtype=jnp.bool_), shape(nb, 2), shape(3, 1),
         shape(k, words), shape(k), k=k, use_kernel=True,
         interpret=cfg.interpret, sketch=sketch).as_text()
 

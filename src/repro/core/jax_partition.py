@@ -16,11 +16,13 @@ The pipeline is fully device-resident:
 
 1. *Packing* — the whole permuted U is packed host-side in one vectorized
    sorted pass over the edge array (``pack_bitmask_csr_sparse``; zero
-   Python-level per-vertex work) into per-row *compact word lists* plus a
-   tiny dense side channel for rows with more than ``cap`` nonzero words.
-   No dense ``(n_blocks, B, W)`` stack exists on either host or device:
-   each block's (B, W) bitmask is rebuilt inside the scan by a 12K-element
-   scatter-add (``_rebuild_nbr``).
+   Python-level per-vertex work) into per-row *compact word lists* of the
+   first ``cap`` nonzero words, plus a flat list of the *overflow words*
+   of the rows that have more (row, word, value entries, with a span per
+   block).  No dense ``(…, W)`` array exists on the host, and none is
+   copied to the device: each block's (B, W) bitmask is rebuilt inside
+   the scan by a 12K-element scatter-add plus its overflow span
+   (``_rebuild_nbr``).
 
 2. *One dispatch* — ``blocked_partition_u_impl`` issues a single jitted
    ``jax.lax.scan`` over the block stack (``_partition_scan``) with the
@@ -193,18 +195,34 @@ class PackedBlocks(NamedTuple):
     The dense (B, W) bitmask of a block is *not* stored — it is rebuilt on
     device inside the scan from the compact word lists (a 12K-element
     scatter-add per block), so the packing ships ~cap words per vertex
-    instead of W.  The rare rows with more than ``cap`` nonzero words ride
-    along densely in ``tr_masks`` and overwrite their rebuilt row.
+    instead of W.  The rare rows with more than ``cap`` nonzero words
+    (``trunc``) keep their first ``cap`` words in ``widx``/``vals``; the
+    words past those, their *overflow words*, ride in one flat list of
+    (block-local row, word index, word value) entries shared by all
+    blocks, which block b reads from ``overflow_spans[b]``.  Entries past
+    the last span are ``(0, 0, 0)`` padding: scatter-adding one is a no-op.
     """
 
     valid: np.ndarray     # (n_blocks, B) bool — False for padding rows
     widx: np.ndarray      # (n_blocks, B, cap) int32 nonzero-word indices
     vals: np.ndarray      # (n_blocks, B, cap) int32 word values at widx
     trunc: np.ndarray     # (n_blocks, B) bool — row has > cap nonzero words
-    tr_ids: np.ndarray    # (n_blocks, TB) int32 local row of each truncated
-                          #   row; B (out of range → dropped) for padding
-    tr_masks: np.ndarray  # (n_blocks, TB, W) int32 full masks of those rows
+    overflow_spans: np.ndarray  # (n_blocks, 2) int32 [start, stop) of each
+                                #   block's entries in overflow_words
+    overflow_words: np.ndarray  # (3, L) int32 rows: block-local row, word
+                                #   index, word value; L a power of two
     order: np.ndarray     # (num_u,) int64 — global vertex id per packed row
+
+
+def _overflow_slots(words: int, floor: int, slots: int) -> int:
+    """Capacity L of the overflow-word list for a feed of ``words``
+    entries: ``slots`` (the capacity already compiled) while the feed fits
+    in it, else the smallest power of two ≥ 2 × max(words, floor) — so a
+    session's L only grows, and only on a feed that would not fit."""
+    need = int(max(words, floor))
+    if slots and need <= slots:
+        return slots
+    return 1 << max(2 * need - 1, 0).bit_length()
 
 
 def pack_graph_blocks(
@@ -213,27 +231,32 @@ def pack_graph_blocks(
     order: np.ndarray | None = None,
     cap: int = 48,
     tb_pad: int | None = None,
+    min_slots: int = 0,
 ) -> PackedBlocks:
     """Pack all of U (in ``order``) into padded (n_blocks, B, …) stacks.
 
     Fully vectorized: one CSR gather + one sorted pass over the edge array
-    yields the compact word lists and the truncated-row side channel.  No
-    per-vertex Python work, and no dense (n, W) array on the host.
+    yields the compact word lists and, from the same pass, the overflow
+    words of the truncated rows (each row's nonzero words past its first
+    ``cap``).  No per-vertex Python work, and no dense (…, W) array on the
+    host.
 
-    ``tb_pad`` rounds the truncated-row side-channel width TB up to the
-    next power of two ≥ max(TB, tb_pad).  Padding entries carry
-    ``tr_ids == B`` (dropped on device), so the output is bit-equivalent —
-    the point is shape stability: streaming feeds re-pack same-sized chunks
-    whose natural TB jitters with the data, and a stable TB keeps every
-    feed on the already-compiled scan.
+    The overflow list holds ``_overflow_slots(words, floor, min_slots)``
+    entries, ``floor`` = ``tb_pad · cap · n_blocks`` (as many words as
+    ``tb_pad`` fully truncated rows a block would spill, counted at cap),
+    or 0 when ``tb_pad`` is None.  Padding entries are ``(0, 0, 0)``, so
+    the output is bit-equivalent at any capacity — the point is shape
+    stability: streaming feeds re-pack same-sized chunks whose overflow
+    count jitters with the data, and a capacity that holds (``min_slots``,
+    the session's high-water mark) keeps every feed on the
+    already-compiled scan.
     """
     n = graph.num_u
     if order is None:
         order = np.arange(n, dtype=np.int64)
     order = np.asarray(order, dtype=np.int64)
-    uniq, wordvals, widx, vals, trunc = pack_bitmask_csr_sparse(
-        graph.u_indptr, graph.u_indices, graph.num_v, rows=order, cap=cap)[:5]
-    W = (graph.num_v + 31) // 32
+    uniq, wordvals, widx, vals, trunc, _, W, pos = pack_bitmask_csr_sparse(
+        graph.u_indptr, graph.u_indices, graph.num_v, rows=order, cap=cap)
     n_blocks = max(1, -(-n // block))
     pad = n_blocks * block - n
     if pad:
@@ -241,33 +264,25 @@ def pack_graph_blocks(
         vals = np.pad(vals, [(0, pad), (0, 0)])
         trunc = np.pad(trunc, [(0, pad)])
     valid = (np.arange(n_blocks * block) < n).reshape(n_blocks, block)
-    # side channel: full masks of truncated rows, grouped per block
-    t_rows = np.flatnonzero(trunc)                       # padded row ids
-    t_block = t_rows // block
-    t_counts = np.bincount(t_block, minlength=n_blocks)
-    TB = max(1, int(t_counts.max()) if t_rows.size else 1)
-    if tb_pad is not None:
-        TB = max(TB, tb_pad)
-        TB = 1 << (TB - 1).bit_length()
-    tr_ids = np.full((n_blocks, TB), block, np.int32)    # block == dropped
-    tr_masks = np.zeros((n_blocks, TB, W), np.int32)
-    if t_rows.size:
-        t_starts = np.concatenate([[0], np.cumsum(t_counts)[:-1]])
-        slot = np.arange(t_rows.size, dtype=np.int64) - t_starts[t_block]
-        tr_ids[t_block, slot] = (t_rows % block).astype(np.int32)
-        trunc_idx = np.full(n_blocks * block, -1, np.int64)
-        trunc_idx[t_rows] = t_block * TB + slot
-        r = uniq // W
-        member = trunc[r]
-        tr_masks.reshape(-1, W)[trunc_idx[r[member]], uniq[member] % W] = \
-            wordvals[member]
+    # overflow words: sorted by (row, word), so each block's are contiguous
+    over = np.flatnonzero(pos >= cap)
+    rows, words = np.divmod(uniq[over], W)
+    floor = 0 if tb_pad is None else tb_pad * cap * n_blocks
+    L = _overflow_slots(over.size, floor, min_slots)
+    overflow_words = np.zeros((3, L), np.int32)
+    overflow_words[0, :over.size] = rows % block
+    overflow_words[1, :over.size] = words
+    overflow_words[2, :over.size] = wordvals[over]
+    stops = np.cumsum(np.bincount(rows // block, minlength=n_blocks))
+    overflow_spans = np.stack(
+        [np.concatenate([[0], stops[:-1]]), stops], axis=1).astype(np.int32)
     return PackedBlocks(
         valid=valid,
         widx=widx.reshape(n_blocks, block, cap),
         vals=vals.reshape(n_blocks, block, cap),
         trunc=trunc.reshape(n_blocks, block),
-        tr_ids=tr_ids,
-        tr_masks=tr_masks,
+        overflow_spans=overflow_spans,
+        overflow_words=overflow_words,
         order=order,
     )
 
@@ -334,18 +349,39 @@ def _assign_block(
 # --------------------------------------------------------------------------
 # Rounds-based device-resident block greedy.
 # --------------------------------------------------------------------------
-def _rebuild_nbr(widx: jax.Array, vals: jax.Array,
-                 tr_ids: jax.Array, tr_masks: jax.Array) -> jax.Array:
-    """Densify a block's (B, W) bitmask from its compact word lists.
+# overflow entries scatter-added per while-loop step of ``_rebuild_nbr``
+_OVERFLOW_PAGE = 1024
+
+
+def _rebuild_nbr(widx: jax.Array, vals: jax.Array, span: jax.Array,
+                 overflow: jax.Array, W: int) -> jax.Array:
+    """Densify a block's (B, W) bitmask from its compact word lists and
+    its ``span`` = [start, stop) of the ``overflow`` (3, L) entries.
 
     Padding slots all target word 0 with value 0, so scatter-*add* is
-    duplicate-safe; truncated rows are then overwritten with their full
-    masks (tr_ids == B ⇒ dropped)."""
+    duplicate-safe; a truncated row's overflow words are distinct from its
+    first ``cap`` words, so adding them sets them.  The span is read in
+    fixed pages of ``dynamic_slice``d entries, so an empty span costs one
+    loop test; entries of a page outside the span add 0."""
     B, _ = widx.shape
-    W = tr_masks.shape[-1]
     nbr = jnp.zeros((B, W), jnp.int32)
     nbr = nbr.at[jnp.arange(B, dtype=jnp.int32)[:, None], widx].add(vals)
-    return nbr.at[tr_ids].set(tr_masks, mode="drop")
+    L = overflow.shape[1]
+    page = min(_OVERFLOW_PAGE, L)
+    lane = jnp.arange(page, dtype=jnp.int32)
+    start, stop = span[0], span[1]
+
+    def add_page(carry):
+        off, nbr = carry
+        at = jnp.minimum(off, L - page)   # where dynamic_slice would clamp
+        row, word, val = jax.lax.dynamic_slice(overflow, (0, at), (3, page))
+        idx = at + lane
+        val = jnp.where((idx >= off) & (idx < stop), val, 0)
+        return off + page, nbr.at[row, word].add(val)
+
+    _, nbr = jax.lax.while_loop(lambda c: c[0] < stop, add_page,
+                                (start, nbr))
+    return nbr
 
 
 def _assign_block_rounds(
@@ -353,8 +389,8 @@ def _assign_block_rounds(
     widx: jax.Array,      # (B, cap) int32
     vals: jax.Array,      # (B, cap) int32
     trunc: jax.Array,     # (B,) bool
-    tr_ids: jax.Array,    # (TB,) int32
-    tr_masks: jax.Array,  # (TB, W) int32
+    span: jax.Array,      # (2,) int32 [start, stop) into overflow
+    overflow: jax.Array,  # (3, L) int32 overflow-word entries
     s_masks: jax.Array,   # (k, W) int32
     sizes: jax.Array,     # (k,) int32
     *,
@@ -377,7 +413,7 @@ def _assign_block_rounds(
     same integer program at a smaller W — so the flag changes nothing
     there, which is precisely why the exact-parity regression holds.
     """
-    nbr = _rebuild_nbr(widx, vals, tr_ids, tr_masks)
+    nbr = _rebuild_nbr(widx, vals, span, overflow, s_masks.shape[1])
     B, W = nbr.shape
     retired0 = ~valid
     parts0 = jnp.full((B,), -1, jnp.int32)
@@ -503,8 +539,8 @@ def _partition_scan(
     widx: jax.Array,      # (n_blocks, B, cap) int32
     vals: jax.Array,      # (n_blocks, B, cap) int32
     trunc: jax.Array,     # (n_blocks, B) bool
-    tr_ids: jax.Array,    # (n_blocks, TB) int32
-    tr_masks: jax.Array,  # (n_blocks, TB, W) int32
+    overflow_spans: jax.Array,  # (n_blocks, 2) int32
+    overflow_words: jax.Array,  # (3, L) int32 — not scanned: all blocks'
     s_masks: jax.Array,   # (k, W) int32 — donated
     sizes: jax.Array,     # (k,) int32 — donated
     *,
@@ -518,13 +554,13 @@ def _partition_scan(
     def per_block(carry, xs):
         s, sz = carry
         parts, s, sz = _assign_block_rounds(
-            *xs, s, sz, k=k, use_kernel=use_kernel, interpret=interpret,
-            sketch=sketch)
+            *xs, overflow_words, s, sz, k=k, use_kernel=use_kernel,
+            interpret=interpret, sketch=sketch)
         return (s, sz), parts
 
     (s_masks, sizes), parts = jax.lax.scan(
         per_block, (s_masks, sizes),
-        (valid, widx, vals, trunc, tr_ids, tr_masks))
+        (valid, widx, vals, trunc, overflow_spans))
     return parts, s_masks, sizes
 
 
@@ -577,8 +613,8 @@ def blocked_partition_u_impl(
     parts_blocks, s_out, _ = _partition_scan(
         jnp.asarray(packed.valid), jnp.asarray(packed.widx),
         jnp.asarray(packed.vals), jnp.asarray(packed.trunc),
-        jnp.asarray(packed.tr_ids), jnp.asarray(packed.tr_masks),
-        s_masks, sizes,
+        jnp.asarray(packed.overflow_spans),
+        jnp.asarray(packed.overflow_words), s_masks, sizes,
         k=k, use_kernel=use_kernel, interpret=interpret, sketch=sketch)
     if not as_numpy:
         flat = parts_blocks.reshape(-1)[: graph.num_u]
@@ -681,10 +717,10 @@ def blocked_partition_u_hostloop(
 
 def _pad_block_stack(packed: PackedBlocks, n_total: int) -> PackedBlocks:
     """Append ``n_total - n_blocks`` empty blocks (all rows padding: valid
-    False, tr_ids == B ⇒ dropped) so a block stack divides evenly into
+    False, an empty overflow span) so a block stack divides evenly into
     per-worker shards and merge groups.  Empty blocks assign nothing and
     leave (S, sizes) untouched, so trailing padding is parity-safe."""
-    nb, B = packed.valid.shape
+    nb = packed.valid.shape[0]
     if n_total == nb:
         return packed
     e = n_total - nb
@@ -692,15 +728,12 @@ def _pad_block_stack(packed: PackedBlocks, n_total: int) -> PackedBlocks:
     def pad0(a):
         return np.pad(a, [(0, e)] + [(0, 0)] * (a.ndim - 1))
 
-    tr_pad = np.full((e, packed.tr_ids.shape[1]), B, np.int32)
-    return PackedBlocks(
+    return packed._replace(
         valid=pad0(packed.valid),
         widx=pad0(packed.widx),
         vals=pad0(packed.vals),
         trunc=pad0(packed.trunc),
-        tr_ids=np.concatenate([packed.tr_ids, tr_pad]),
-        tr_masks=pad0(packed.tr_masks),
-        order=packed.order,
+        overflow_spans=pad0(packed.overflow_spans),
     )
 
 
@@ -709,12 +742,13 @@ def _parallel_scan_fn(devices, k: int, merge_every: int, use_kernel: bool,
                       interpret: bool | None, sketch: bool = False):
     """Build (and cache) the jitted shard_map pipeline for one worker mesh.
 
-    Each device scans its (n_super, merge_every, B, …) block stack against a
-    device-local *stale* copy of the packed (k, W) server sets; after every
-    ``merge_every`` blocks the shards merge by all_gather + lattice OR on
-    uint32 words (the bulk-synchronous image of the Alg 4 server union-push,
-    τ ≡ merge_every − 1 blocks of staleness) and sizes by psum of the local
-    deltas.  The (S, sizes) carries are donated, so nothing round-trips
+    Each device scans its (n_super, merge_every, B, …) block stack (the
+    overflow-word list, indexed by each block's span, is replicated)
+    against a device-local *stale* copy of the packed (k, W) server sets;
+    after every ``merge_every`` blocks the shards merge by all_gather +
+    lattice OR on uint32 words (the bulk-synchronous image of the Alg 4
+    server union-push, τ ≡ merge_every − 1 blocks of staleness) and sizes
+    by psum of the local deltas.  The (S, sizes) carries are donated, so nothing round-trips
     through the host between merges.  Also returns the total number of
     changed words pushed across all merges (the delta-encoded worker→server
     traffic of Alg 4 worker line 9).
@@ -724,11 +758,11 @@ def _parallel_scan_fn(devices, k: int, merge_every: int, use_kernel: bool,
     axis = "parsa_workers"
     mesh = Mesh(np.asarray(devices), (axis,))
 
-    def body(valid, widx, vals, trunc, tr_ids, tr_masks, s_masks, sizes):
+    def body(valid, widx, vals, trunc, spans, overflow, s_masks, sizes):
         # shard_map leaves the sharded leading axis in place with local
         # extent 1 — drop it, then group blocks into merge rounds.
-        valid, widx, vals, trunc, tr_ids, tr_masks = (
-            x[0] for x in (valid, widx, vals, trunc, tr_ids, tr_masks))
+        valid, widx, vals, trunc, spans = (
+            x[0] for x in (valid, widx, vals, trunc, spans))
         nb = valid.shape[0]
         n_super = nb // merge_every
 
@@ -738,8 +772,8 @@ def _parallel_scan_fn(devices, k: int, merge_every: int, use_kernel: bool,
         def per_block(carry, xs):
             s, sz = carry
             parts, s, sz = _assign_block_rounds(
-                *xs, s, sz, k=k, use_kernel=use_kernel, interpret=interpret,
-                sketch=sketch)
+                *xs, overflow, s, sz, k=k, use_kernel=use_kernel,
+                interpret=interpret, sketch=sketch)
             return (s, sz), parts
 
         def super_step(carry, xs):
@@ -760,14 +794,13 @@ def _parallel_scan_fn(devices, k: int, merge_every: int, use_kernel: bool,
 
         (s_masks, sizes, pushed), parts = jax.lax.scan(
             super_step, (s_masks, sizes, jnp.int32(0)),
-            tuple(group(x) for x in
-                  (valid, widx, vals, trunc, tr_ids, tr_masks)))
+            tuple(group(x) for x in (valid, widx, vals, trunc, spans)))
         pushed = jax.lax.psum(pushed, axis)
         return parts[None], s_masks, sizes, pushed
 
     fn = jax.shard_map(
         body, mesh=mesh,
-        in_specs=(P(axis),) * 6 + (P(), P()),
+        in_specs=(P(axis),) * 5 + (P(), P(), P()),
         out_specs=(P(axis), P(), P(), P()),
         check_vma=False)
     return jax.jit(fn, donate_argnums=(6, 7))
@@ -898,12 +931,12 @@ def _run_parallel_packed_scan(
                     devices=[d.id for d in devices])
     parts_blocks, s_out, sizes_out, pushed_words = fn(
         shard(packed.valid), shard(packed.widx), shard(packed.vals),
-        shard(packed.trunc), shard(packed.tr_ids), shard(packed.tr_masks),
-        s_masks, sizes)
+        shard(packed.trunc), shard(packed.overflow_spans),
+        jnp.asarray(packed.overflow_words), s_masks, sizes)
     # where the per-worker outputs landed: one shard per mesh device
     annotate_dispatch(shard_devices=sorted(
         s.device.id for s in parts_blocks.addressable_shards))
-    W = packed.tr_masks.shape[-1]
+    W = s_masks.shape[-1]
     n_super = nb_per // merge_every
     traffic = {
         "pushed_bytes": 4 * int(pushed_words),
@@ -1003,24 +1036,24 @@ def shard_parsa_step(k: int, axis: str = "data", use_kernel: bool = False,
     """
 
     def body(valid: jax.Array, widx: jax.Array, vals: jax.Array,
-             trunc: jax.Array, tr_ids: jax.Array, tr_masks: jax.Array,
+             trunc: jax.Array, spans: jax.Array, overflow: jax.Array,
              s_masks: jax.Array, sizes: jax.Array):
         def per_block(carry, xs):
             s_masks, sizes = carry
-            val, wi, va, tr, ti, tm = xs
+            val, wi, va, tr, sp = xs
             if select == "rounds":
                 parts, s_masks, sizes = _assign_block_rounds(
-                    val, wi, va, tr, ti, tm, s_masks, sizes,
+                    val, wi, va, tr, sp, overflow, s_masks, sizes,
                     k=k, use_kernel=use_kernel, interpret=interpret)
             else:
                 parts, s_masks, sizes = _assign_block(
-                    _rebuild_nbr(wi, va, ti, tm), s_masks, sizes, val,
+                    _rebuild_nbr(wi, va, sp, overflow, s_masks.shape[1]),
+                    s_masks, sizes, val,
                     k=k, use_kernel=use_kernel, interpret=interpret)
             return (s_masks, sizes), parts
 
         (s_masks, sizes), parts = jax.lax.scan(
-            per_block, (s_masks, sizes),
-            (valid, widx, vals, trunc, tr_ids, tr_masks))
+            per_block, (s_masks, sizes), (valid, widx, vals, trunc, spans))
         # server union-push: OR-merge neighbor sets across the data axis
         gathered = jax.lax.all_gather(s_masks, axis)  # (n_dev, k, W)
         merged = jax.lax.reduce(

@@ -294,7 +294,8 @@ class ElasticSession:
         parts2, m2, _ = _partition_scan(
             jnp.asarray(packed.valid), jnp.asarray(packed.widx),
             jnp.asarray(packed.vals), jnp.asarray(packed.trunc),
-            jnp.asarray(packed.tr_ids), jnp.asarray(packed.tr_masks),
+            jnp.asarray(packed.overflow_spans),
+            jnp.asarray(packed.overflow_words),
             jnp.zeros((2, arena.W_cap), jnp.int32),
             jnp.zeros((2,), jnp.int32),
             k=2, use_kernel=base.use_kernel, interpret=base.interpret,
@@ -455,7 +456,8 @@ class ElasticSession:
         parts_sub, s_out, sz_out = _partition_scan(
             jnp.asarray(packed.valid), jnp.asarray(packed.widx),
             jnp.asarray(packed.vals), jnp.asarray(packed.trunc),
-            jnp.asarray(packed.tr_ids), jnp.asarray(packed.tr_masks),
+            jnp.asarray(packed.overflow_spans),
+            jnp.asarray(packed.overflow_words),
             jnp.asarray(masks), jnp.asarray(sizes_live),
             k=k, use_kernel=base.use_kernel, interpret=base.interpret,
             sketch=self.stream.sketch is not None)

@@ -265,13 +265,14 @@ def pack_bitmask_csr_sparse(
     ever touching a dense (n, W) array — the caller chooses where (and
     whether) to densify: ``pack_bitmask_csr_compact`` scatters on the host,
     while ``blocked_partition_u`` never densifies globally at all — it
-    ships only the compact lists (plus the truncated rows' full masks,
-    built from (uniq, wordvals)) and rebuilds each block's (B, W) bitmask
-    on device inside the scan.
+    ships only the compact lists plus the truncated rows' overflow words
+    (the entries at ``pos >= cap``) and rebuilds each block's (B, W)
+    bitmask on device inside the scan.
 
     Returns (uniq (nnz,) int64 flat indices into the (n, W) mask,
     wordvals (nnz,) int32, widx (n, cap) int32, vals (n, cap) int32,
-    truncated (n,) bool, n, W).
+    truncated (n,) bool, n, W, pos (nnz,) int64 — each entry's rank among
+    its row's nonzero words).
     """
     n, _, row_ids, cols = _gather_row_cols(indptr, indices, rows)
     W = (num_v + 31) // 32
@@ -279,7 +280,8 @@ def pack_bitmask_csr_sparse(
     vals = np.zeros((n, cap), dtype=np.uint32)
     if cols.size == 0:
         return (np.zeros(0, np.int64), np.zeros(0, np.int32), widx,
-                vals.view(np.int32), np.zeros(n, bool), n, W)
+                vals.view(np.int32), np.zeros(n, bool), n, W,
+                np.zeros(0, np.int64))
     fw = row_ids * W + (cols >> 5)            # flat (row, word) key per edge
     bit = (np.int64(1) << (cols & 31)).astype(np.uint32)
     srt = np.argsort(fw, kind="stable")
@@ -299,7 +301,7 @@ def pack_bitmask_csr_sparse(
     widx.reshape(-1)[flat] = (uniq[keep] % W).astype(np.int32)
     vals.reshape(-1)[flat] = acc[keep]
     return (uniq, acc.view(np.int32), widx, vals.view(np.int32),
-            counts > cap, n, W)
+            counts > cap, n, W, pos)
 
 
 def pack_bitmask_csr_compact(
@@ -314,7 +316,7 @@ def pack_bitmask_csr_compact(
     Returns (masks (n, W) int32, widx (n, cap) int32, vals (n, cap) int32,
     truncated (n,) bool), matching the two-step reference exactly.
     """
-    uniq, wordvals, widx, vals, trunc, n, W = pack_bitmask_csr_sparse(
+    uniq, wordvals, widx, vals, trunc, n, W, _ = pack_bitmask_csr_sparse(
         indptr, indices, num_v, rows=rows, cap=cap)
     masks = np.zeros(n * W, dtype=np.int32)
     masks[uniq] = wordvals

@@ -16,9 +16,11 @@ live sets as the carry.
 
 ``feed`` is O(chunk) work and O(1) XLA dispatches: one ``_partition_scan``
 launch (the same jitted program ``device_scan`` runs, carries donated) plus
-one popcount-metrics launch.  Same-shaped chunks hit the jit cache; the
-truncated-row side channel is padded to powers of two (``tb_pad``) so data
-jitter does not retrigger compilation.  With ``workers > 1`` the chunk's
+one popcount-metrics launch.  Same-shaped chunks hit the jit cache: the
+list of overflow words (the words of rows past ``cap``) keeps a
+power-of-two capacity that starts at a floor set by ``tb_pad`` and only
+grows, on a feed that would not fit, so data jitter does not retrigger
+compilation.  With ``workers > 1`` the chunk's
 blocks fan out across the ``parallel_device`` mesh through the cached
 shard_map pipeline, with *randomized* block→worker assignment
 (arXiv:1502.02606: random data distribution preserves the distributed
@@ -82,7 +84,8 @@ class ParsaStreamConfig:
     drift_min_feeds: int = 2       # history before a trigger is allowed
     repartition: str = "drift"     # "drift" (auto) | "never" (manual only)
     repartition_frac: float = 0.02  # §4.4 global-init sample; 0 = cold
-    tb_pad: int = 8                # truncated-row channel pad (pow2 bucket)
+    tb_pad: int = 8                # overflow-list floor: truncated rows
+                                   #   a block holds at cap words each
     shuffle_blocks: bool = True    # randomized block→worker assignment
 
     def __post_init__(self):
@@ -137,16 +140,19 @@ class StreamUpdate:
 _PHASE_SUMS = ("partition_u", "total")
 
 
-def _packed_counters(packed) -> dict[str, int]:
+def _packed_counters(packed, grew: bool) -> dict[str, int]:
     """Per-feed counts of the packed blocks a scan is given: the bytes of
-    the six arrays put on the device, and the truncated-row channel's
-    used and total slots (``tr_ids == B`` marks an empty slot)."""
-    B = packed.valid.shape[1]
+    the six arrays put on the device; the truncated rows, the overflow
+    words they carry and the overflow list's capacity; and whether this
+    feed raised that capacity (a new shape: the scan compiles)."""
     arrays = (packed.valid, packed.widx, packed.vals, packed.trunc,
-              packed.tr_ids, packed.tr_masks)
+              packed.overflow_spans, packed.overflow_words)
+    spans = packed.overflow_spans
     return {"upload_bytes": sum(int(x.nbytes) for x in arrays),
-            "channel_rows": int((packed.tr_ids != B).sum()),
-            "channel_slots": int(packed.tr_ids.size)}
+            "channel_rows": int(packed.trunc.sum()),
+            "channel_words": int((spans[:, 1] - spans[:, 0]).sum()),
+            "channel_slots": int(packed.overflow_words.shape[1]),
+            "channel_grew": int(grew)}
 
 
 class StreamSession:
@@ -205,6 +211,7 @@ class StreamSession:
         self._tasks = 0
         self._stale = 0
         self._migrated = 0
+        self._channel_slots = 0   # overflow-list capacity: only grows
 
     # ------------------------------------------------------------- feeding
     def feed(self, chunk: BipartiteGraph,
@@ -263,8 +270,12 @@ class StreamSession:
             with step("pack") as span:
                 packed = pack_graph_blocks(
                     self.arena.capacity_graph(chunk), base.block_size,
-                    order=order, cap=base.cap, tb_pad=self.config.tb_pad)
-                counters = _packed_counters(packed)
+                    order=order, cap=base.cap, tb_pad=self.config.tb_pad,
+                    min_slots=self._channel_slots)
+                slots = packed.overflow_words.shape[1]
+                counters = _packed_counters(packed,
+                                            slots > self._channel_slots)
+                self._channel_slots = slots
                 span.set_metadata(**counters)
 
             traffic = None
@@ -278,8 +289,8 @@ class StreamSession:
                     blocks = (
                         jnp.asarray(packed.valid), jnp.asarray(packed.widx),
                         jnp.asarray(packed.vals), jnp.asarray(packed.trunc),
-                        jnp.asarray(packed.tr_ids),
-                        jnp.asarray(packed.tr_masks))
+                        jnp.asarray(packed.overflow_spans),
+                        jnp.asarray(packed.overflow_words))
                 with step("launch"):
                     parts_blocks, s_out, sz_out = _partition_scan(
                         *blocks, self.arena.s_masks, self.arena.sizes,
@@ -315,8 +326,8 @@ class StreamSession:
                     migration = self.repartition()
                     metrics = self._popcount_metrics()
             with step("release"):
-                # free the packed blocks (up to GBs of truncation channel)
-                # on the feed's clock rather than untimed at its return
+                # free the packed blocks on the feed's clock rather than
+                # untimed at its return
                 packed = blocks = None
         self.n_feeds += 1
         timings["total"] = time.perf_counter() - t_total

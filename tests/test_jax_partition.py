@@ -81,16 +81,88 @@ def test_compact_row_words_identity():
         assert full == comp
 
 
+def _row_words(g, rows):
+    """Distinct nonzero words of each row, in ``rows`` order."""
+    return [np.unique(g.u_indices[g.u_indptr[u]:g.u_indptr[u + 1]] // 32)
+            for u in rows]
+
+
 def test_pack_graph_blocks_shapes_and_trunc_side_channel():
     g = text_like(700, 900, mean_len=30, seed=4)
-    packed = pack_graph_blocks(g, 256, cap=4)  # tiny cap → lots of trunc
+    cap = 4
+    packed = pack_graph_blocks(g, 256, cap=cap)  # tiny cap → lots of trunc
     nb = -(-g.num_u // 256)
     assert packed.valid.shape == (nb, 256)
     assert packed.valid.sum() == g.num_u
     assert packed.trunc.any()  # cap=4 must truncate on this graph
-    # every truncated row appears exactly once in the side channel
-    t_total = int(packed.trunc.sum())
-    assert int((packed.tr_ids < 256).sum()) == t_total
+    # the overflow list carries exactly the words past cap of every row
+    words = sum(max(0, len(w) - cap) for w in _row_words(g, packed.order))
+    spans = packed.overflow_spans
+    assert spans.shape == (nb, 2) and spans.dtype == np.int32
+    assert spans[0, 0] == 0 and spans[-1, 1] == words
+    assert np.array_equal(spans[1:, 0], spans[:-1, 1])   # contiguous
+    L = packed.overflow_words.shape[1]
+    assert packed.overflow_words.shape == (3, L)
+    assert L & (L - 1) == 0 and 2 * words <= L < 4 * words
+    assert not packed.overflow_words[:, words:].any()  # (0, 0, 0) padding
+    rows = packed.overflow_words[0, :words]
+    assert ((rows >= 0) & (rows < 256)).all()
+    # every row with overflow words is a truncated row of its block
+    blk = np.repeat(np.arange(nb), spans[:, 1] - spans[:, 0])
+    assert packed.trunc[blk, rows].all()
+    assert np.array_equal(
+        np.unique(blk * 256 + rows), np.flatnonzero(packed.trunc.ravel()))
+
+
+@pytest.mark.parametrize("cap", [4, 48])
+def test_rebuild_nbr_matches_dense_masks(cap):
+    """Each block's rebuilt (B, W) bitmask — compact words plus its
+    overflow span — equals the dense packing bit for bit."""
+    from repro.core.jax_partition import _rebuild_nbr
+
+    g = text_like(700, 900, mean_len=30, seed=4)
+    block = 256
+    order = np.random.default_rng(5).permutation(g.num_u)
+    packed = pack_graph_blocks(g, block, order=order, cap=cap)
+    W = (g.num_v + 31) // 32
+    dense = pack_bitmask_csr(g.u_indptr, g.u_indices, g.num_v, rows=order)
+    dense = np.pad(dense, [(0, packed.valid.size - g.num_u), (0, 0)])
+    over = jnp.asarray(packed.overflow_words)
+    rebuild = jax.jit(_rebuild_nbr, static_argnums=4)
+    for b in range(packed.valid.shape[0]):
+        got = rebuild(jnp.asarray(packed.widx[b]), jnp.asarray(packed.vals[b]),
+                      jnp.asarray(packed.overflow_spans[b]), over, W)
+        assert np.array_equal(np.asarray(got),
+                              dense[b * block:(b + 1) * block])
+    assert packed.trunc.any() == (cap == 4)
+
+
+def test_overflow_pages_past_the_list_end(monkeypatch):
+    """Pages that straddle spans, and a last page that ``dynamic_slice``
+    clamps back over entries already added, add each entry of the span
+    once and no other."""
+    from repro.core import jax_partition as jp
+
+    g = text_like(300, 5000, mean_len=60, seed=6)
+    packed = pack_graph_blocks(g, 64, cap=2)
+    spans = packed.overflow_spans
+    words = int(spans[-1, 1])
+    last = int(spans[-1, 1] - spans[-1, 0])
+    assert last >= 4
+    W = (g.num_v + 31) // 32
+    dense = pack_bitmask_csr(g.u_indptr, g.u_indices, g.num_v)
+    dense = np.pad(dense, [(0, packed.valid.size - g.num_u), (0, 0)])
+    # an exactly full list; a page of ``last - 1`` makes the last block's
+    # second page clamp back over all but one of its first page's entries
+    over = jnp.asarray(packed.overflow_words[:, :words])
+    for page in (3, last - 1):
+        monkeypatch.setattr(jp, "_OVERFLOW_PAGE", page)
+        for b in range(packed.valid.shape[0]):
+            got = jp._rebuild_nbr(
+                jnp.asarray(packed.widx[b]), jnp.asarray(packed.vals[b]),
+                jnp.asarray(spans[b]), over, W)
+            assert np.array_equal(np.asarray(got),
+                                  dense[b * 64:(b + 1) * 64]), (page, b)
 
 
 # ------------------------------------------------- fused cost+select kernel
@@ -448,7 +520,8 @@ def test_shard_parsa_step_single_device():
     parts, merged, sizes = fn(
         jnp.asarray(packed.valid), jnp.asarray(packed.widx),
         jnp.asarray(packed.vals), jnp.asarray(packed.trunc),
-        jnp.asarray(packed.tr_ids), jnp.asarray(packed.tr_masks),
+        jnp.asarray(packed.overflow_spans),
+        jnp.asarray(packed.overflow_words),
         jnp.zeros((k, W), jnp.int32), jnp.zeros((k,), jnp.int32))
     parts = np.asarray(parts).reshape(-1)[: g.num_u]
     assert (parts >= 0).all()
@@ -480,7 +553,8 @@ def test_shard_parsa_step_padded_blocks(select):
     parts, merged, sizes = fn(
         jnp.asarray(packed.valid), jnp.asarray(packed.widx),
         jnp.asarray(packed.vals), jnp.asarray(packed.trunc),
-        jnp.asarray(packed.tr_ids), jnp.asarray(packed.tr_masks),
+        jnp.asarray(packed.overflow_spans),
+        jnp.asarray(packed.overflow_words),
         jnp.zeros((k, W), jnp.int32), jnp.zeros((k,), jnp.int32))
     parts = np.asarray(parts).reshape(-1)
     real, pad = parts[: g.num_u], parts[g.num_u:]

@@ -318,6 +318,88 @@ def test_update_dispatches_reports_repartition_launches():
         assert u.dispatches["partition_scan"] == 1  # the repair's full scan
 
 
+def _overflow_chunk(n, words_per_row, seed):
+    """``n`` rows; row i holds ``words_per_row[i]`` distinct packed words
+    (a few bits each) of a 256-word (8,192-column) space."""
+    rng = np.random.default_rng(seed)
+    us, vs = [], []
+    for u, m in enumerate(words_per_row):
+        cols = (rng.choice(256, m, replace=False)[:, None] * 32
+                + rng.integers(0, 32, (m, 3))).ravel()
+        us.append(np.full(cols.size, u))
+        vs.append(cols)
+    return from_edges(n, 8192, np.concatenate(us), np.concatenate(vs))
+
+
+def _overflow_session():
+    base = ParsaConfig(k=4, backend="device_scan", block_size=64, cap=4,
+                       use_kernel=False, refine_v=False, seed=7)
+    return StreamSession(ParsaStreamConfig(base=base, tb_pad=1,
+                                           repartition="never"), num_v=8192)
+
+
+def _sets_from_parts(sess):
+    """The packed server sets the fed rows and their parts imply."""
+    g = sess.arena.graph()
+    want = np.zeros((sess.k, (g.num_v + 31) // 32), np.uint32)
+    for u in range(g.num_u):
+        want[sess.parts[u]] |= pack_bitmask(
+            [g.neighbors(u)], g.num_v).view(np.uint32)[0]
+    return want
+
+
+def test_overflow_capacity_holds_across_feeds():
+    """Chunks whose overflow-word counts vary under the list's capacity
+    run on the scan compiled for the first feed: no further compile."""
+    from repro.core.jax_partition import _partition_scan
+
+    rng = np.random.default_rng(11)
+    sess = _overflow_session()
+    ups = []
+    # 128 rows (two blocks); (rows past cap, words past cap each): the
+    # first sets the capacity at 2·30 → 64, the rest fit under it
+    for c, (hubs, extra) in enumerate([(6, 5), (0, 0), (3, 7), (8, 7),
+                                       (5, 2)]):
+        lens = np.full(128, 3)
+        lens[rng.choice(128, hubs, replace=False)] = 4 + extra
+        ups.append(sess.feed(_overflow_chunk(128, lens, seed=c)))
+        if c == 0:
+            compiled = _partition_scan._cache_size()
+    assert _partition_scan._cache_size() == compiled
+    words = [u.counters["channel_words"] for u in ups]
+    assert words == [30, 0, 21, 56, 10]
+    assert {u.counters["channel_slots"] for u in ups} == {64}
+    assert [u.counters["channel_grew"] for u in ups] == [1, 0, 0, 0, 0]
+    assert np.array_equal(sess.arena.masks_np().view(np.uint32),
+                          _sets_from_parts(sess))
+
+
+def test_overflow_capacity_grows_once_and_never_shrinks():
+    """A chunk whose overflow words do not fit raises the capacity once
+    (``channel_grew`` on that feed alone); a smaller chunk after it keeps
+    the larger capacity, on the same compiled scan."""
+    from repro.core.jax_partition import _partition_scan
+
+    sess = _overflow_session()
+    small = np.full(128, 3)
+    small[:4] = 10                             # 4 rows × 6 words past cap
+    big = np.full(128, 3)
+    big[:40] = 12                              # 40 rows × 8 words past cap
+    chunks = [small, small, big, small, big]
+    ups = [sess.feed(_overflow_chunk(128, lens, seed=c))
+           for c, lens in enumerate(chunks)]
+    assert [u.counters["channel_words"] for u in ups] == [24, 24, 320, 24, 320]
+    # floor tb_pad·cap·n_blocks = 8, so 2·24 → 64; 2·320 → 1024
+    assert [u.counters["channel_slots"] for u in ups] == [64, 64, 1024,
+                                                          1024, 1024]
+    assert [u.counters["channel_grew"] for u in ups] == [1, 0, 1, 0, 0]
+    before = _partition_scan._cache_size()
+    sess.feed(_overflow_chunk(128, small, seed=9))
+    assert _partition_scan._cache_size() == before
+    assert np.array_equal(sess.arena.masks_np().view(np.uint32),
+                          _sets_from_parts(sess))
+
+
 def test_stream_config_validation():
     with pytest.raises(ValueError, match="device backend"):
         ParsaStreamConfig(base=ParsaConfig(k=4, backend="host"))

@@ -73,16 +73,19 @@ def test_feed_counters_count_the_packed_blocks():
     packed = pack_graph_blocks(sess.arena.capacity_graph(g), 64,
                                order=order, cap=CAP, tb_pad=4)
     arrays = (packed.valid, packed.widx, packed.vals, packed.trunc,
-              packed.tr_ids, packed.tr_masks)
+              packed.overflow_spans, packed.overflow_words)
     assert upd.counters["upload_bytes"] == sum(a.nbytes for a in arrays)
     words = [np.unique(g.u_indices[g.u_indptr[u]:g.u_indptr[u + 1]] // 32)
              for u in range(g.num_u)]
     over_cap = sum(len(w) > CAP for w in words)
     assert over_cap > 0
     assert upd.counters["channel_rows"] == over_cap
-    assert upd.counters["channel_slots"] == (
-        packed.tr_ids.shape[0] * packed.tr_ids.shape[1])
-    assert upd.counters["channel_rows"] <= upd.counters["channel_slots"]
+    assert upd.counters["channel_words"] == sum(
+        max(0, len(w) - CAP) for w in words)
+    assert upd.counters["channel_slots"] == packed.overflow_words.shape[1]
+    assert upd.counters["channel_words"] <= upd.counters["channel_slots"]
+    assert upd.counters["channel_grew"] == 1     # the first feed sets it
+    assert sess.feed(g).counters["channel_grew"] == 0
 
 
 def test_feed_spans_land_in_the_profiler_trace(tmp_path):

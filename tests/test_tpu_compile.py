@@ -102,10 +102,10 @@ def test_packed_union_delta_kernel_compiles(one_chip):
 def test_kernel_path_partition_scan_compiles(one_chip):
     """A few blocks of the whole ``device_scan`` program, fused select
     kernel inside the block scan, at the deployment's packed width."""
-    nb, b, cap, tb, k = 4, 256, 48, 1, 16
+    nb, b, cap, slots, k = 4, 256, 48, 1 << 14, 16
     args = (_i32((nb, b), one_chip, jnp.bool_), _i32((nb, b, cap), one_chip),
             _i32((nb, b, cap), one_chip), _i32((nb, b), one_chip, jnp.bool_),
-            _i32((nb, tb), one_chip), _i32((nb, tb, W), one_chip),
+            _i32((nb, 2), one_chip), _i32((3, slots), one_chip),
             _i32((k, W), one_chip), _i32((k,), one_chip))
     text = _partition_scan.lower(*args, k=k, use_kernel=True,
                                  interpret=False).compile().as_text()
