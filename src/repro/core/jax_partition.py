@@ -737,10 +737,32 @@ def _pad_block_stack(packed: PackedBlocks, n_total: int) -> PackedBlocks:
     )
 
 
+_WORKER_AXIS = "parsa_workers"
+
+
+@functools.cache
+def _worker_mesh(devices: tuple):
+    """The one-axis mesh of Alg 4's workers, one worker per device."""
+    from jax.sharding import Mesh
+
+    return Mesh(np.asarray(devices), (_WORKER_AXIS,))
+
+
+def _replicate_on_workers(x, devices: tuple) -> jax.Array:
+    """``x`` replicated on every worker device of the mesh (no copy where
+    it already is): how the live ``(S, sizes)`` of an Alg 4 stream stay
+    on the mesh from feed to feed, so the scan's donation holds."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    return jax.device_put(x, NamedSharding(_worker_mesh(devices), P()))
+
+
 @functools.cache
 def _parallel_scan_fn(devices, k: int, merge_every: int, use_kernel: bool,
                       interpret: bool | None, sketch: bool = False):
-    """Build (and cache) the jitted shard_map pipeline for one worker mesh.
+    """Build (and cache) the jitted shard_map program for one worker mesh,
+    named ``_parallel_partition_scan`` (``jit__parallel_partition_scan``
+    in a profiler trace).
 
     Each device scans its (n_super, merge_every, B, …) block stack (the
     overflow-word list, indexed by each block's span, is replicated)
@@ -748,17 +770,18 @@ def _parallel_scan_fn(devices, k: int, merge_every: int, use_kernel: bool,
     after every ``merge_every`` blocks the shards merge by all_gather +
     lattice OR on uint32 words (the bulk-synchronous image of the Alg 4
     server union-push, τ ≡ merge_every − 1 blocks of staleness) and sizes
-    by psum of the local deltas.  The (S, sizes) carries are donated, so nothing round-trips
-    through the host between merges.  Also returns the total number of
-    changed words pushed across all merges (the delta-encoded worker→server
-    traffic of Alg 4 worker line 9).
+    by psum of the local deltas.  The (S, sizes) carries are donated and
+    come out replicated on the mesh, so nothing round-trips through the
+    host, or through one chip, between merges or between feeds.  Also
+    returns the total number of changed words pushed across all merges
+    (the delta-encoded worker→server traffic of Alg 4 worker line 9).
     """
-    from jax.sharding import Mesh, PartitionSpec as P
+    from jax.sharding import PartitionSpec as P
 
-    axis = "parsa_workers"
-    mesh = Mesh(np.asarray(devices), (axis,))
+    axis = _WORKER_AXIS
 
-    def body(valid, widx, vals, trunc, spans, overflow, s_masks, sizes):
+    def _parallel_partition_scan(valid, widx, vals, trunc, spans, overflow,
+                                 s_masks, sizes):
         # shard_map leaves the sharded leading axis in place with local
         # extent 1 — drop it, then group blocks into merge rounds.
         valid, widx, vals, trunc, spans = (
@@ -799,7 +822,7 @@ def _parallel_scan_fn(devices, k: int, merge_every: int, use_kernel: bool,
         return parts[None], s_masks, sizes, pushed
 
     fn = jax.shard_map(
-        body, mesh=mesh,
+        _parallel_partition_scan, mesh=_worker_mesh(devices),
         in_specs=(P(axis),) * 5 + (P(), P(), P()),
         out_specs=(P(axis), P(), P(), P()),
         check_vma=False)
@@ -852,28 +875,49 @@ def _biased_perm(targets: np.ndarray, nb: int, nb_per: int,
     return np.concatenate(out)
 
 
-def _run_parallel_packed_scan(
+class WorkerBlocks(NamedTuple):
+    """One block stack placed on the worker mesh by
+    ``_place_parallel_blocks``: the five per-block stacks as (workers,
+    nb_per, …) arrays, row ``w`` on worker ``w``'s device, and the
+    overflow-word list replicated on every worker."""
+
+    arrays: tuple            # valid, widx, vals, trunc, spans, overflow
+    devices: tuple           # the mesh, one worker per device
+    nb_per: int              # blocks per worker, whole merge groups
+    merge_every: int
+    perm: np.ndarray | None  # stack → sharded block order, None = identity
+
+    @property
+    def n_super(self) -> int:
+        """Merge rounds: every worker merges after each group."""
+        return self.nb_per // self.merge_every
+
+    def in_stack_order(self, parts_blocks) -> np.ndarray:
+        """The (workers, n_super, merge_every, B) parts back on the host,
+        flattened in block-stack order."""
+        by_block = np.asarray(parts_blocks)
+        by_block = by_block.reshape(-1, by_block.shape[-1])
+        if self.perm is not None:
+            by_block = by_block[np.argsort(self.perm)]
+        return by_block.reshape(-1)
+
+
+def _place_parallel_blocks(
     packed: PackedBlocks,
-    s_masks: jax.Array,
-    sizes: jax.Array,
     *,
-    k: int,
     workers: int,
     merge_every: int,
-    use_kernel: bool,
-    interpret: bool | None,
     devices: tuple | None = None,
     shuffle_rng: np.random.Generator | None = None,
     worker_weights: np.ndarray | None = None,
-    count_name: str = "parallel_partition_scan",
-    sketch: bool = False,
-) -> tuple[jax.Array, jax.Array, jax.Array, dict, np.ndarray | None]:
-    """Shared Alg 4 core of ``parallel_blocked_partition_u_impl`` and the
-    streaming parallel feed: pad the block stack to whole per-worker merge
-    groups, shard it across the worker mesh (optionally in a randomized
-    block→worker order drawn from ``shuffle_rng`` — the arXiv:1502.02606
-    assignment the stream uses), and run the cached shard_map pipeline
-    against the (donated) live ``(s_masks, sizes)``.
+) -> WorkerBlocks:
+    """Place step of the Alg 4 core: pad the block stack to whole
+    per-worker merge groups, deal it to the workers (optionally in a
+    randomized block→worker order drawn from ``shuffle_rng`` — the
+    arXiv:1502.02606 assignment the stream uses), and copy each worker's
+    share from the host straight to its own device through a
+    ``NamedSharding`` over the worker mesh; the overflow list goes to
+    every device.
 
     ``worker_weights`` (workers-long, nonnegative, e.g. the inverse-EWMA
     speeds from ``runtime.straggler.StragglerEWMA``) biases the block
@@ -884,15 +928,9 @@ def _run_parallel_packed_scan(
     shrinks.  The merge cadence is untouched: each worker still syncs
     every ``merge_every`` blocks, so the τ ≡ merge_every − 1 staleness
     bound of the bounded-delay model holds regardless of the bias.
-
-    Returns ``(parts_blocks, s_out, sizes_out, traffic, perm)`` where
-    ``parts_blocks`` is the device (workers, n_super, merge_every, B)
-    output in *sharded* block order (flatten + ``argsort(perm)`` to
-    recover stack order when a permutation was applied; ``perm`` is None
-    only when neither shuffle nor weights were given), and ``traffic`` is
-    the push/pull dict in bitmask-word bytes — the single source of the
-    Alg 4 counter formulas.
     """
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
     devices = resolve_worker_devices(workers, devices)
     nb = packed.valid.shape[0]
     if worker_weights is not None and workers > 1:
@@ -918,33 +956,68 @@ def _run_parallel_packed_scan(
         perm = (shuffle_rng.permutation(total) if shuffle_rng is not None
                 else None)
 
+    mesh = _worker_mesh(devices)
+    by_worker = NamedSharding(mesh, P(_WORKER_AXIS))
+
     def shard(x):
         if perm is not None:
             x = x[perm]
-        return jnp.asarray(x.reshape((workers, nb_per) + x.shape[1:]))
+        return jax.device_put(x.reshape((workers, nb_per) + x.shape[1:]),
+                              by_worker)
 
-    fn = _parallel_scan_fn(devices, k, merge_every, use_kernel, interpret,
-                           sketch)
+    arrays = tuple(shard(x) for x in (packed.valid, packed.widx, packed.vals,
+                                      packed.trunc, packed.overflow_spans))
+    arrays += (jax.device_put(packed.overflow_words,
+                              NamedSharding(mesh, P())),)
+    return WorkerBlocks(arrays, devices, nb_per, merge_every, perm)
+
+
+def _launch_parallel_scan(
+    blocks: WorkerBlocks,
+    s_masks: jax.Array,
+    sizes: jax.Array,
+    *,
+    k: int,
+    use_kernel: bool,
+    interpret: bool | None,
+    count_name: str = "parallel_partition_scan",
+    sketch: bool = False,
+) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """Run step of the Alg 4 core: launch ``_parallel_partition_scan`` on
+    placed blocks against the live ``(s_masks, sizes)``, replicated on the
+    mesh first where they are not (the first feed, or after a repair) and
+    donated.  Returns without waiting: ``(parts_blocks, s_out, sizes_out,
+    pushed_words)``, the parts in *sharded* block order (see
+    ``WorkerBlocks.in_stack_order``), the rest replicated on the mesh."""
+    devices = blocks.devices
+    s_masks = _replicate_on_workers(s_masks, devices)
+    sizes = _replicate_on_workers(sizes, devices)
+    fn = _parallel_scan_fn(devices, k, blocks.merge_every, use_kernel,
+                           interpret, sketch)
     _count_dispatch(count_name,
                     nbytes=int(s_masks.nbytes) + int(sizes.nbytes),
-                    k=k, workers=workers, blocks=nb_per * workers,
+                    k=k, workers=len(devices),
+                    blocks=blocks.nb_per * len(devices),
                     devices=[d.id for d in devices])
-    parts_blocks, s_out, sizes_out, pushed_words = fn(
-        shard(packed.valid), shard(packed.widx), shard(packed.vals),
-        shard(packed.trunc), shard(packed.overflow_spans),
-        jnp.asarray(packed.overflow_words), s_masks, sizes)
+    out = fn(*blocks.arrays, s_masks, sizes)
     # where the per-worker outputs landed: one shard per mesh device
     annotate_dispatch(shard_devices=sorted(
-        s.device.id for s in parts_blocks.addressable_shards))
-    W = s_masks.shape[-1]
-    n_super = nb_per // merge_every
-    traffic = {
+        s.device.id for s in out[0].addressable_shards))
+    return out
+
+
+def _parallel_traffic(blocks: WorkerBlocks, pushed_words: int, k: int,
+                      W: int) -> dict:
+    """The push/pull dict of one Alg 4 scan in bitmask-word bytes — the
+    single source of the Alg 4 counter formulas: each worker pushes its
+    changed words and pulls the full packed (k, W) sets at every merge."""
+    workers, n_super = len(blocks.devices), blocks.n_super
+    return {
         "pushed_bytes": 4 * int(pushed_words),
         "pulled_bytes": 4 * workers * n_super * k * W,
         "tasks": workers * n_super,
         "stale_pushes_missed": n_super * workers * (workers - 1),
     }
-    return parts_blocks, s_out, sizes_out, traffic, perm
 
 
 def parallel_blocked_partition_u_impl(
@@ -1004,10 +1077,12 @@ def parallel_blocked_partition_u_impl(
     packed = pack_graph_blocks(graph, block, order=order, cap=cap)
     if timings is not None:
         timings["pack"] = time.perf_counter() - t_pack
-    parts_blocks, s_out, _, traffic, _ = _run_parallel_packed_scan(
-        packed, s_masks, sizes, k=k, workers=workers,
-        merge_every=merge_every, use_kernel=use_kernel, interpret=interpret,
-        devices=devices, sketch=sketch)
+    blocks = _place_parallel_blocks(packed, workers=workers,
+                                    merge_every=merge_every, devices=devices)
+    parts_blocks, s_out, _, pushed = _launch_parallel_scan(
+        blocks, s_masks, sizes, k=k, use_kernel=use_kernel,
+        interpret=interpret, sketch=sketch)
+    traffic = _parallel_traffic(blocks, int(pushed), k, s_masks.shape[-1])
     if not as_numpy:
         flat = parts_blocks.reshape(-1)[: graph.num_u]
         parts = jnp.zeros((graph.num_u,), jnp.int32).at[

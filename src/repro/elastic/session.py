@@ -17,7 +17,7 @@ mid-stream, composing primitives the repo already ships:
     ``repartition()`` — the baseline ``bench_chaos`` beats.
   * straggler-aware feeds — a ``StragglerEWMA`` of per-worker scan times
     biases the randomized block→worker assignment away from slow
-    workers (``_run_parallel_packed_scan(worker_weights=...)``),
+    workers (``_place_parallel_blocks(worker_weights=...)``),
     keeping staleness inside τ instead of reacting to it.
 
 Every mutation is metered in ``TrafficCounters.migration_bytes`` (same

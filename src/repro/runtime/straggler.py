@@ -67,7 +67,7 @@ class StragglerEWMA:
     instead of letting a slow worker accumulate staleness toward the τ
     clamp, the scheduler *prevents* the lag by handing it fewer blocks —
     ``weights()`` are inverse-EWMA speeds, consumed by
-    ``_run_parallel_packed_scan(worker_weights=...)``.  ``floor`` bounds
+    ``_place_parallel_blocks(worker_weights=...)``.  ``floor`` bounds
     how far a worker can be starved (a 10× straggler still gets ≥ floor ×
     its fair share), so a recovered worker keeps receiving enough blocks
     for its EWMA to re-converge instead of being written off forever.

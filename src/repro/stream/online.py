@@ -20,12 +20,14 @@ one popcount-metrics launch.  Same-shaped chunks hit the jit cache: the
 list of overflow words (the words of rows past ``cap``) keeps a
 power-of-two capacity that starts at a floor set by ``tb_pad`` and only
 grows, on a feed that would not fit, so data jitter does not retrigger
-compilation.  With ``workers > 1`` the chunk's
-blocks fan out across the ``parallel_device`` mesh through the cached
-shard_map pipeline, with *randomized* block→worker assignment
-(arXiv:1502.02606: random data distribution preserves the distributed
-greedy's approximation guarantees in expectation) and OR-merges every
-``merge_every`` blocks.
+compilation.  With ``workers > 1`` (Alg 4) the chunk's blocks fan out
+across the ``parallel_device`` mesh, with *randomized* block→worker
+assignment (arXiv:1502.02606: random data distribution preserves the
+distributed greedy's approximation guarantees in expectation): each
+worker's share is copied straight to its own device, one
+``_parallel_partition_scan`` launch runs every worker's blocks and
+OR-merges the sets every ``merge_every`` blocks, and the live sets stay
+replicated on the mesh between feeds.  Both paths time the same phases.
 
 Drift repair: assignments are never revisited by ``feed``, so under
 distribution drift the partition decays.  A ``DriftTracker`` watches the
@@ -49,8 +51,10 @@ from ..core.bipartite import BipartiteGraph
 from ..core.costs import PartitionMetrics
 from ..core.jax_partition import (
     _count_dispatch,
+    _launch_parallel_scan,
+    _parallel_traffic,
     _partition_scan,
-    _run_parallel_packed_scan,
+    _place_parallel_blocks,
     blocked_partition_u_impl,
     pack_graph_blocks,
     parallel_blocked_partition_u_impl,
@@ -155,6 +159,18 @@ def _packed_counters(packed, grew: bool) -> dict[str, int]:
             "channel_grew": int(grew)}
 
 
+def _merge_counters(blocks, traffic: TrafficCounters, k: int,
+                    W: int) -> dict[str, int]:
+    """Per-feed counts of an Alg 4 scan's merges: the rounds, the bytes
+    each chip receives in their all-gathers (the other workers' (k, W)
+    int32 sets, every round) and the changed words the workers pushed
+    (``traffic.pushed_bytes`` in words)."""
+    workers = len(blocks.devices)
+    return {"merge_rounds": blocks.n_super,
+            "merge_bytes": blocks.n_super * (workers - 1) * k * W * 4,
+            "pushed_words": traffic.pushed_bytes // 4}
+
+
 class StreamSession:
     """Partition a graph that grows over time, entirely on device.
 
@@ -220,7 +236,7 @@ class StreamSession:
 
         ``worker_weights`` (parallel feeds only) biases the randomized
         block→worker assignment toward faster workers — see
-        ``_run_parallel_packed_scan``; the elastic layer supplies an EWMA
+        ``_place_parallel_blocks``; the elastic layer supplies an EWMA
         of per-worker scan times here so stragglers receive fewer blocks.
 
         One jitted scan dispatch (plus one popcount-metrics dispatch) per
@@ -237,13 +253,15 @@ class StreamSession:
 
         Every host step falls in one ``repro.obs.phase``, timed into
         ``timings`` and marked as the profiler span ``parsa.feed.<phase>``
-        (``feed=`` the ordinal): ``prepare``, ``pack``, ``upload``,
-        ``launch``, ``wait`` (the host blocked on the scan), ``append``,
-        ``metrics``, ``repartition`` when drift fires, and ``release``
-        (the packed blocks' host memory freed).  Alg 4 feeds
-        time ``scan`` in place of upload/launch/wait.  ``partition_u`` is
-        the sum from upload to append; ``counters`` are
-        ``_packed_counters``'s, also attributes of the ``pack`` span.
+        (``feed=`` the ordinal): ``prepare``, ``pack``, ``upload`` (the
+        packed blocks put on the device; with Alg 4 each worker's share
+        on its own device), ``launch``, ``wait`` (the host blocked on the
+        scan's parts), ``append``, ``metrics``, ``repartition`` when drift
+        fires, and ``release`` (the packed blocks freed).  One-chip and
+        Alg 4 feeds time the same phases.  ``partition_u`` is the sum from
+        upload to append; ``counters`` are ``_packed_counters``'s, also
+        attributes of the ``pack`` span, and on Alg 4 feeds
+        ``_merge_counters``'s too, also attributes of the ``wait`` span.
         """
         import jax.numpy as jnp
 
@@ -300,11 +318,31 @@ class StreamSession:
                 with step("wait"):
                     flat = np.asarray(parts_blocks).reshape(-1)[:n]
             else:
-                # upload, launch and wait in one: the Alg 4 runner is
-                # shared with the one-shot facade
-                with step("scan"):
-                    flat, s_out, sz_out, traffic = self._feed_parallel(
-                        packed, n, worker_weights)
+                # the Alg 4 core the one-shot facade runs, step by step
+                with step("upload"):
+                    blocks = _place_parallel_blocks(
+                        packed, workers=self.config.workers,
+                        merge_every=base.merge_every,
+                        shuffle_rng=(self._rng if self.config.shuffle_blocks
+                                     else None),
+                        worker_weights=worker_weights)
+                with step("launch"):
+                    parts_blocks, s_out, sz_out, pushed = \
+                        _launch_parallel_scan(
+                            blocks, self.arena.s_masks, self.arena.sizes,
+                            k=self.k, use_kernel=base.use_kernel,
+                            interpret=base.interpret,
+                            count_name="stream_feed_scan",
+                            sketch=self.sketch is not None)
+                with step("wait") as span:
+                    flat = blocks.in_stack_order(parts_blocks)[:n]
+                    traffic = TrafficCounters(**_parallel_traffic(
+                        blocks, int(pushed), self.k, self.arena.W_cap))
+                    self._accumulate(traffic)
+                    merge = _merge_counters(blocks, traffic, self.k,
+                                            self.arena.W_cap)
+                    counters.update(merge)
+                    span.set_metadata(**merge)
             with step("append"):
                 # scan succeeded — commit: live sets, CSR append, parts
                 self.arena.s_masks, self.arena.sizes = s_out, sz_out
@@ -314,7 +352,7 @@ class StreamSession:
                 self._store_parts(u_start, parts_chunk)
             timings["partition_u"] = sum(
                 timings.get(name, 0.0)
-                for name in ("upload", "launch", "wait", "scan", "append"))
+                for name in ("upload", "launch", "wait", "append"))
 
             decision = migration = None
             with step("metrics"):
@@ -360,32 +398,6 @@ class StreamSession:
         for i, name in enumerate(phases):
             sp.child(name, i * share, share, wall_s=timings[name])
         tr.advance(1.0)
-
-    def _feed_parallel(self, packed, n: int,
-                       worker_weights: np.ndarray | None = None):
-        """Fan one chunk's blocks across the worker mesh: the shared Alg 4
-        core (``_run_parallel_packed_scan``) with randomized block→worker
-        assignment, against the live donated (S, sizes)."""
-        base = self.config.base
-        workers = self.config.workers
-        shuffle = (self._rng if self.config.shuffle_blocks and workers > 1
-                   else None)
-        parts_blocks, s_out, sz_out, traffic_d, perm = \
-            _run_parallel_packed_scan(
-                packed, self.arena.s_masks, self.arena.sizes, k=self.k,
-                workers=workers, merge_every=base.merge_every,
-                use_kernel=base.use_kernel, interpret=base.interpret,
-                shuffle_rng=shuffle, worker_weights=worker_weights,
-                count_name="stream_feed_scan",
-                sketch=self.sketch is not None)
-        B = packed.valid.shape[1]
-        by_block = np.asarray(parts_blocks).reshape(-1, B)
-        if perm is not None:
-            by_block = by_block[np.argsort(perm)]
-        flat = by_block.reshape(-1)[:n]
-        traffic = TrafficCounters(**traffic_d)
-        self._accumulate(traffic)
-        return flat, s_out, sz_out, traffic
 
     @property
     def parts(self) -> np.ndarray:

@@ -1,5 +1,6 @@
-"""Compile the Pallas kernels of the partition path, and the kernel-path
-scan, for a described TPU v5e at deployment widths — no chip needed.
+"""Compile the Pallas kernels of the partition path, the kernel-path
+scan, and the Alg 4 program across a 2x2 mesh, for a described TPU v5e
+at deployment widths — no chip needed.
 
 Interpret mode accepts what Mosaic refuses (dynamic slices of vectors,
 scoped-VMEM overflow), so only these compiles show that the kernels run on
@@ -18,7 +19,12 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.core.jax_partition import _partition_scan
+from repro.core.jax_partition import (
+    _WORKER_AXIS,
+    _parallel_scan_fn,
+    _partition_scan,
+    _worker_mesh,
+)
 from repro.kernels.parsa_cost.parsa_cost import parsa_cost_kernel
 from repro.kernels.parsa_cost.select import (
     SKETCH_KERNEL_MAX_WORDS,
@@ -33,10 +39,9 @@ B = 1024
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
 
     if importlib.util.find_spec("libtpu") is None:
         pytest.skip("no TPU compiler (libtpu) in this installation")
@@ -49,9 +54,16 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _i32(shape, sharding, dtype=jnp.int32):
@@ -110,3 +122,28 @@ def test_kernel_path_partition_scan_compiles(one_chip):
     text = _partition_scan.lower(*args, k=k, use_kernel=True,
                                  interpret=False).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+def test_parallel_partition_scan_compiles_for_four_chips(topo):
+    """The Alg 4 program (``_parallel_partition_scan``) over the described
+    2x2 host at ``criteo_k16_w4``'s widths: each worker's blocks sharded
+    to its chip, the overflow list and the live sets replicated, the sets
+    merged by an all-gather and the sizes by an all-reduce."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    devices = tuple(topo.devices)
+    mesh = _worker_mesh(devices)
+    by_worker = NamedSharding(mesh, P(_WORKER_AXIS))
+    replicated = NamedSharding(mesh, P())
+    workers, nb, b, cap, slots, k = 4, 2, 256, 48, 1 << 17, 16
+    args = (_i32((workers, nb, b), by_worker, jnp.bool_),
+            _i32((workers, nb, b, cap), by_worker),
+            _i32((workers, nb, b, cap), by_worker),
+            _i32((workers, nb, b), by_worker, jnp.bool_),
+            _i32((workers, nb, 2), by_worker),
+            _i32((3, slots), replicated),
+            _i32((k, W), replicated), _i32((k,), replicated))
+    fn = _parallel_scan_fn(devices, k, 1, False, False)
+    text = fn.lower(*args).compile().as_text()
+    assert text.startswith("HloModule jit__parallel_partition_scan")
+    assert "all-gather(" in text and "all-reduce(" in text
