@@ -20,9 +20,11 @@ The pipeline is fully device-resident:
    first ``cap`` nonzero words, plus a flat list of the *overflow words*
    of the rows that have more (row, word, value entries, with a span per
    block).  No dense ``(…, W)`` array exists on the host, and none is
-   copied to the device: each block's (B, W) bitmask is rebuilt inside
-   the scan by a 12K-element scatter-add plus its overflow span
-   (``_rebuild_nbr``).
+   copied to the device.  On the jnp path a block with no truncated row
+   is greedy-assigned from its word lists alone; the kernel path, and a
+   jnp-path block that holds a truncated row, rebuild the block's (B, W)
+   bitmask inside the scan by a 12K-element scatter-add plus its
+   overflow span (``_rebuild_nbr``).
 
 2. *One dispatch* — ``blocked_partition_u_impl`` issues a single jitted
    ``jax.lax.scan`` over the block stack (``_partition_scan``) with the
@@ -44,10 +46,14 @@ packed ``s_masks`` so the device path warm-starts with host-path parity.
    one vertex per partition with progressive retirement — on TPU via the
    fused cost+select Pallas kernel (``parsa_cost_select``), which reduces
    the (B, k) cost tile to per-partition (min, argmin) inside VMEM without
-   materializing it, enabling B=1024 blocks; on CPU (``use_kernel=False``)
-   from a down-dated cost tile whose per-round update gathers only the
-   ≤ cap nonzero words of each selected vertex's mask (dense fallback via
-   ``lax.cond`` when a hub vertex exceeds cap — bit-exact either way).
+   materializing it, enabling B=1024 blocks; on the jnp path
+   (``use_kernel=False``) from a carried cost tile down-dated by the ≤ cap
+   words of each selected vertex.  A block with no truncated row never
+   leaves compact space: its rounds hold S at the block's words only,
+   down-date the tile by matching word lists, and the block's commits
+   reach S by one scatter after its last round.  A block that holds a
+   hub row past cap takes the densified branch (``lax.cond`` per block)
+   — bit-exact either way.
 
    Both paths produce *identical* assignments to the sequential per-vertex
    reference ``blocked_partition_u_hostloop`` (property-tested), because a
@@ -384,6 +390,180 @@ def _rebuild_nbr(widx: jax.Array, vals: jax.Array, span: jax.Array,
     return nbr
 
 
+def _full_rounds(B: int, k: int) -> int:
+    """Rounds after the catch-up round: it may assign as little as one
+    vertex."""
+    return -(-(B - 1) // k)
+
+
+def _block_rounds(tile0, select, commit, valid, s_masks, sizes, *, k):
+    """Greedy-assign a block in balanced rounds, however its rows are
+    held.  Returns (parts, S', sizes').
+
+    ``s_masks`` is whatever the caller holds the sets as.
+    ``select(tile, s_masks, retired, ord_, en)`` picks one row per slot
+    as ``select_greedy_from_cost`` does; ``commit(s_masks, u_safe, act,
+    ord_, inv)`` ORs the picked rows into their partitions' sets and
+    returns ``(S', dec)``, ``dec`` the (B, k) down-date of the cost tile
+    in slot order against the sets before the commit, or None where no
+    tile is carried.  ``inv`` maps partitions to slots (None: identity).
+    """
+    B = valid.shape[0]
+    iota_b = jnp.arange(B, dtype=jnp.int32)
+    iota_k = jnp.arange(k, dtype=jnp.int32)
+
+    def round_body(state, ord_, en):
+        """One greedy round.  ord_ = None means the identity visit order
+        0..k-1 (every round after the catch-up), which skips all the
+        slot→partition permutation gathers."""
+        tile, s_masks, sizes, parts, retired = state
+        u_sel, c_sel = select(tile, s_masks, retired, ord_, en)
+        act = c_sel < BIG
+        u_safe = jnp.where(act, u_sel, 0)
+        inv = None if ord_ is None else jnp.argsort(ord_)
+        # commit: S_i |= N(u), sizes, parts, retirement, tile down-date
+        s_masks, dec = commit(s_masks, u_safe, act, ord_, inv)
+        match = (iota_b[:, None] == u_sel[None, :]) & act[None, :]  # (B, k)
+        assigned = match.any(axis=1)
+        retired = retired | assigned
+        if ord_ is None:
+            sizes = sizes + act.astype(jnp.int32)
+            col_id = (match * iota_k[None, :]).sum(axis=1).astype(jnp.int32)
+        else:
+            sizes = sizes + act[inv].astype(jnp.int32)
+            col_id = (match * ord_[None, :]).sum(axis=1).astype(jnp.int32)
+            dec = None if dec is None else dec[:, inv]
+        if dec is not None:
+            tile = tile - dec
+        parts = jnp.where(assigned, col_id, parts)
+        return tile, s_masks, sizes, parts, retired
+
+    # catch-up round (partition visit order = stable argsort of sizes,
+    # only the min-sized partitions may pick), then full identity rounds
+    parts0 = jnp.full((B,), -1, jnp.int32)
+    ord0 = jnp.argsort(sizes, stable=True).astype(jnp.int32)
+    en0 = sizes[ord0] == jnp.min(sizes)
+    state = round_body((tile0, s_masks, sizes, parts0, ~valid), ord0, en0)
+    en_all = jnp.ones((k,), bool)
+
+    def full_round(state, _):
+        return round_body(state, None, en_all), None
+
+    (_, s_masks, sizes, parts, _), _ = jax.lax.scan(
+        full_round, state, None, length=_full_rounds(B, k))
+    return parts, s_masks, sizes
+
+
+def _select_from_tile(tile, s_masks, retired, ord_, en):
+    return select_greedy_from_cost(tile, retired, ord_, en)
+
+
+def _or_rows(s_masks, rows, act, inv):
+    """S_i |= the dense row picked for partition i (``rows`` in slot
+    order, inactive slots add nothing)."""
+    add = jnp.where(act[:, None], rows, 0)
+    return s_masks | (add if inv is None else add[inv])
+
+
+def _dense_block_rounds(valid, widx, vals, trunc, span, overflow, s_masks,
+                        sizes, *, k):
+    """The jnp greedy of a block that holds a truncated row: its (B, W)
+    bitmask is densified (``_rebuild_nbr``), the initial tile is the dense
+    product, and a round down-dates the tile densely only when it picks a
+    truncated row (``lax.cond``; otherwise through the picked rows'
+    compact words, gathered from the transposed mask)."""
+    nbr = _rebuild_nbr(widx, vals, span, overflow, s_masks.shape[1])
+    B, cap = widx.shape
+    # Both sparse gathers run over *transposed* operands so each gathered
+    # index pulls a contiguous row instead of a strided column — XLA CPU's
+    # element gather was the down-date bottleneck (~45% of scan time).
+    nbr_t = nbr.T                                          # (W, B)
+    tile0 = parsa_cost(nbr, s_masks, use_kernel=False)
+
+    def commit(s_masks, u_safe, act, ord_, inv):
+        sel_nbr = nbr[u_safe]                              # (k, W)
+        # Down-date values in compact space: delta_j's nonzero words
+        # are a subset of the selected vertex's word list, so gather S
+        # (pre-update) at widx[u_j] instead of materializing delta
+        # full-width.  Padding slots carry vals == 0 → contribute 0.
+        d_widx = widx[u_safe]                              # (k, cap)
+        if ord_ is None:
+            s_at = jnp.take_along_axis(s_masks, d_widx, axis=1)
+        else:
+            s_at = s_masks[ord_[:, None], d_widx]
+        d_vals = jnp.where(act[:, None], vals[u_safe] & ~s_at, 0)
+
+        def sparse_dec(_):
+            g = nbr_t[d_widx.reshape(-1)].reshape(k, cap, B)
+            return jax.lax.population_count(
+                g & d_vals[:, :, None]).astype(jnp.int32).sum(1).T
+
+        def dense_dec(_):
+            s_cols = s_masks if ord_ is None else s_masks[ord_]
+            delta = jnp.where(act[:, None], sel_nbr & ~s_cols, 0)
+            return jax.lax.population_count(
+                nbr[:, None, :] & delta[None]).astype(jnp.int32).sum(-1)
+
+        any_trunc = jnp.any(act & trunc[u_safe])
+        dec = jax.lax.cond(any_trunc, dense_dec, sparse_dec, None)
+        return _or_rows(s_masks, sel_nbr, act, inv), dec
+
+    return _block_rounds(tile0, _select_from_tile, commit, valid, s_masks,
+                         sizes, k=k)
+
+
+def _compact_block_rounds(valid, widx, vals, s_masks, sizes, *, k):
+    """The jnp greedy of a block with no truncated row, in compact space:
+    its compact word lists are its rows whole, so no (B, W) or (W, B)
+    array is built.  The rounds hold S only at the block's words, in the
+    word lists' (k, cap, B) layout: ``s_at[i, c, v]`` is S_i at word
+    ``widx[v, c]``.  A round takes the picked rows' fresh bits from it,
+    delta_j = N(u_j) & ~S_j on u_j's ≤ cap words, and matches word
+    lists: ``hit[j, c, v]`` is delta_j at word ``widx[v, c]``, looked up
+    among u_j's words one slot at a time.  The tile's down-date is
+    dec[v, j] = Σ_c popcount(vals[v, c] & hit[j, c, v]), and the commit
+    is ``s_at |= hit``.  Each round's (word, fresh bits) pairs are logged
+    and scatter-added into S once, after the last round (on a v5e one
+    scatter of the whole log costs about two of a round's).  Add is OR
+    there: a partition's fresh bits are disjoint from S and from each
+    other's, a row's words are distinct, and padding slots add 0 at word
+    0."""
+    B, cap = widx.shape
+    iota_b = jnp.arange(B, dtype=jnp.int32)
+    iota_k = jnp.arange(k, dtype=jnp.int32)
+    widx_t, vals_t = widx.T, vals.T                        # (cap, B)
+    s_at = s_masks[:, widx_t]                              # (k, cap, B)
+    # initial tile cost[v, i] = deg(v) − |N(v) ∩ S_i|
+    deg = jax.lax.population_count(vals).astype(jnp.int32).sum(-1)
+    tile0 = deg[:, None] - jax.lax.population_count(
+        s_at & vals_t).astype(jnp.int32).sum(1).T
+    log = jnp.zeros((1 + _full_rounds(B, k), 2, k, cap), jnp.int32)
+
+    def commit(state, u_safe, act, ord_, inv):
+        s_at, log, r = state
+        own = s_at if ord_ is None else s_at[ord_]         # slot order
+        s_row = jnp.where(iota_b == u_safe[:, None, None], own, 0).sum(-1)
+        d_widx = widx[u_safe]                              # (k, cap)
+        d_vals = jnp.where(act[:, None], vals[u_safe] & ~s_row, 0)
+        hit = jnp.zeros((k, cap, B), jnp.int32)
+        for c in range(cap):
+            hit = hit | jnp.where(widx_t == d_widx[:, c, None, None],
+                                  d_vals[:, c, None, None], 0)
+        dec = jax.lax.population_count(
+            vals_t & hit).astype(jnp.int32).sum(1).T       # (B, k)
+        entry = jnp.stack([d_widx, d_vals])                # (2, k, cap)
+        if inv is not None:
+            hit, entry = hit[inv], entry[:, inv]
+        log = jax.lax.dynamic_update_index_in_dim(log, entry, r, 0)
+        return (s_at | hit, log, r + 1), dec
+
+    parts, (_, log, _), sizes = _block_rounds(
+        tile0, _select_from_tile, commit, valid,
+        (s_at, log, jnp.int32(0)), sizes, k=k)
+    part = jnp.broadcast_to(iota_k[:, None], (k, cap))
+    return parts, s_masks.at[part, log[:, 0]].add(log[:, 1]), sizes
+
+
 def _assign_block_rounds(
     valid: jax.Array,     # (B,) bool
     widx: jax.Array,      # (B, cap) int32
@@ -402,9 +582,12 @@ def _assign_block_rounds(
     """Greedy-assign a block in balanced rounds.  Returns (parts, S', sizes').
 
     Identical output to ``_assign_block`` whenever sizes differ by ≤ 1 at
-    entry (property-tested); on the kernel path the cost tile lives only in
-    VMEM (fused cost+select), on the jnp path it is carried and down-dated
-    sparsely via the compact word lists.
+    entry (property-tested).  On the kernel path the cost tile lives only
+    in VMEM (fused cost+select over the densified block).  On the jnp path
+    the tile is carried and down-dated, and the block's rows are held as
+    its input shows they must be: a block with no truncated row runs in
+    compact space (``_compact_block_rounds``), one with a truncated row
+    over its densified mask (``_dense_block_rounds``), chosen on device.
 
     ``sketch=True`` marks the packed width as a sketched domain
     (``repro.sketch``): the kernel path switches to the gridless
@@ -413,120 +596,29 @@ def _assign_block_rounds(
     same integer program at a smaller W — so the flag changes nothing
     there, which is precisely why the exact-parity regression holds.
     """
+    if not use_kernel:
+        dense = functools.partial(_dense_block_rounds, valid, widx, vals,
+                                  trunc, span, overflow, k=k)
+        compact = functools.partial(_compact_block_rounds, valid, widx,
+                                    vals, k=k)
+        return jax.lax.cond(trunc.any(), dense, compact, s_masks, sizes)
+    # Fused cost+select recomputes the (B, k) tile in VMEM each round and
+    # reduces it in the same pass — no tile is carried at all, so the
+    # state holds a placeholder.
     nbr = _rebuild_nbr(widx, vals, span, overflow, s_masks.shape[1])
-    B, W = nbr.shape
-    retired0 = ~valid
-    parts0 = jnp.full((B,), -1, jnp.int32)
-    cap = widx.shape[1]
-    iota_b = jnp.arange(B, dtype=jnp.int32)
+    select_fn = sketch_cost_select if sketch else parsa_cost_select
     iota_k = jnp.arange(k, dtype=jnp.int32)
 
-    if use_kernel:
-        # Fused cost+select recomputes the (B, k) tile in VMEM each round
-        # and reduces it in the same pass — no tile is carried at all, so
-        # the state holds a placeholder.
-        nbr_t = None
-        tile0 = jnp.zeros((1, 1), jnp.int32)
-    else:
-        # jnp path: carry the tile and down-date it sparsely.  Initial tile
-        # cost[v, i] = deg(v) − |N(v) ∩ S_i|: the intersection only touches
-        # each row's ≤ cap nonzero words, so gather S at widx instead of
-        # the dense (B, k, W) product; any truncated row in the block trips
-        # the exact dense fallback (rare for cap ≈ 48).  Both sparse
-        # gathers run over *transposed* operands so each gathered index
-        # pulls a contiguous row instead of a strided column — XLA CPU's
-        # element gather was the down-date bottleneck (~45% of scan time).
-        nbr_t = nbr.T                                      # (W, B)
-        deg = jax.lax.population_count(vals).astype(jnp.int32).sum(-1)
+    def select(_tile, s_masks, retired, ord_, en):
+        return select_fn(nbr, s_masks, retired,
+                         order=iota_k if ord_ is None else ord_, enabled=en,
+                         use_kernel=True, interpret=interpret)
 
-        def sparse_init(_):
-            sg = s_masks.T[widx.reshape(-1)].reshape(B, cap, k)
-            inter = jax.lax.population_count(
-                sg & vals[:, :, None]).astype(jnp.int32).sum(1)  # (B, k)
-            return deg[:, None] - inter
+    def commit(s_masks, u_safe, act, ord_, inv):
+        return _or_rows(s_masks, nbr[u_safe], act, inv), None
 
-        def dense_init(_):
-            return parsa_cost(nbr, s_masks, use_kernel=False)
-
-        tile0 = jax.lax.cond(trunc.any(), dense_init, sparse_init, None)
-
-    def round_body(state, ord_, en):
-        """One greedy round.  ord_ = None means the identity visit order
-        0..k-1 (every round after the catch-up), which skips all the
-        slot→partition permutation gathers."""
-        tile, s_masks, sizes, parts, retired = state
-        if use_kernel:
-            select_fn = sketch_cost_select if sketch else parsa_cost_select
-            u_sel, c_sel = select_fn(
-                nbr, s_masks, retired,
-                order=iota_k if ord_ is None else ord_, enabled=en,
-                use_kernel=True, interpret=interpret)
-        else:
-            u_sel, c_sel = select_greedy_from_cost(tile, retired, ord_, en)
-        act = c_sel < BIG
-        u_safe = jnp.where(act, u_sel, 0)
-        sel_nbr = nbr[u_safe]                              # (k, W)
-        if not use_kernel:
-            # Down-date values in compact space: delta_j's nonzero words
-            # are a subset of the selected vertex's word list, so gather S
-            # (pre-update) at widx[u_j] instead of materializing delta
-            # full-width.  Padding slots carry vals == 0 → contribute 0.
-            d_widx = widx[u_safe]                          # (k, cap)
-            d_sel_vals = vals[u_safe]
-            if ord_ is None:
-                s_at = jnp.take_along_axis(s_masks, d_widx, axis=1)
-            else:
-                s_at = s_masks[ord_[:, None], d_widx]
-            d_vals = jnp.where(act[:, None], d_sel_vals & ~s_at, 0)
-
-            def sparse_dec(_):
-                g = nbr_t[d_widx.reshape(-1)].reshape(k, cap, B)
-                return jax.lax.population_count(
-                    g & d_vals[:, :, None]).astype(jnp.int32).sum(1).T
-
-            def dense_dec(_):
-                s_cols = s_masks if ord_ is None else s_masks[ord_]
-                delta = jnp.where(act[:, None], sel_nbr & ~s_cols, 0)
-                return jax.lax.population_count(
-                    nbr[:, None, :] & delta[None]).astype(jnp.int32).sum(-1)
-
-            any_trunc = jnp.any(act & trunc[u_safe])
-            dec = jax.lax.cond(any_trunc, dense_dec, sparse_dec, None)
-        # commit: S_i |= N(u), sizes, parts, retirement, tile down-date
-        add = jnp.where(act[:, None], sel_nbr, 0)
-        match = (iota_b[:, None] == u_sel[None, :]) & act[None, :]  # (B, k)
-        assigned = match.any(axis=1)
-        retired = retired | assigned
-        if ord_ is None:
-            s_masks = s_masks | add
-            sizes = sizes + act.astype(jnp.int32)
-            col_id = (match * iota_k[None, :]).sum(axis=1).astype(jnp.int32)
-            if not use_kernel:
-                tile = tile - dec
-        else:
-            inv = jnp.argsort(ord_)
-            s_masks = s_masks | add[inv]
-            sizes = sizes + act[inv].astype(jnp.int32)
-            col_id = (match * ord_[None, :]).sum(axis=1).astype(jnp.int32)
-            if not use_kernel:
-                tile = tile - dec[:, inv]
-        parts = jnp.where(assigned, col_id, parts)
-        return tile, s_masks, sizes, parts, retired
-
-    # catch-up round (partition visit order = stable argsort of sizes,
-    # only the min-sized partitions may pick), then full identity rounds
-    ord0 = jnp.argsort(sizes, stable=True).astype(jnp.int32)
-    en0 = sizes[ord0] == jnp.min(sizes)
-    state = round_body((tile0, s_masks, sizes, parts0, retired0), ord0, en0)
-    en_all = jnp.ones((k,), bool)
-
-    def full_round(state, _):
-        return round_body(state, None, en_all), None
-
-    n_full = -(-(B - 1) // k)  # catch-up may assign as little as one vertex
-    (_, s_masks, sizes, parts, _), _ = jax.lax.scan(
-        full_round, state, None, length=n_full)
-    return parts, s_masks, sizes
+    return _block_rounds(jnp.zeros((1, 1), jnp.int32), select, commit,
+                         valid, s_masks, sizes, k=k)
 
 
 @functools.partial(

@@ -147,8 +147,9 @@ _PHASE_SUMS = ("partition_u", "total")
 def _packed_counters(packed, grew: bool) -> dict[str, int]:
     """Per-feed counts of the packed blocks a scan is given: the bytes of
     the six arrays put on the device; the truncated rows, the overflow
-    words they carry and the overflow list's capacity; and whether this
-    feed raised that capacity (a new shape: the scan compiles)."""
+    words they carry and the overflow list's capacity; whether this feed
+    raised that capacity (a new shape: the scan compiles); and the blocks
+    with no truncated row, which the jnp scan runs in compact space."""
     arrays = (packed.valid, packed.widx, packed.vals, packed.trunc,
               packed.overflow_spans, packed.overflow_words)
     spans = packed.overflow_spans
@@ -156,7 +157,8 @@ def _packed_counters(packed, grew: bool) -> dict[str, int]:
             "channel_rows": int(packed.trunc.sum()),
             "channel_words": int((spans[:, 1] - spans[:, 0]).sum()),
             "channel_slots": int(packed.overflow_words.shape[1]),
-            "channel_grew": int(grew)}
+            "channel_grew": int(grew),
+            "compact_blocks": int((~packed.trunc.any(axis=1)).sum())}
 
 
 def _merge_counters(blocks, traffic: TrafficCounters, k: int,

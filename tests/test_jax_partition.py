@@ -1,6 +1,8 @@
 """Device-resident blocked-Parsa pipeline: packing property tests, fused
 cost+select kernel exactness, and single-dispatch scan parity vs the
 sequential per-block host loop."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,6 +10,10 @@ import pytest
 
 from repro.core.bipartite import from_edges
 from repro.core.jax_partition import (
+    _assign_block,
+    _assign_block_rounds,
+    _compact_block_rounds,
+    _dense_block_rounds,
     blocked_partition_u,
     blocked_partition_u_hostloop,
     dispatch_counter,
@@ -215,15 +221,22 @@ def test_select_kernel_conflict_chain():
 # ----------------------------------------------------- scan pipeline parity
 @pytest.mark.parametrize("seed,k,block", [
     (0, 4, 128), (1, 16, 128), (2, 8, 256), (3, 16, 64), (4, 3, 104),
+    (5, 16, 120), (6, 5, 248),
 ])
 def test_scan_pipeline_matches_hostloop(seed, k, block):
-    """Acceptance: the single-dispatch scan returns identical parts_u to
-    the per-block host loop (seed implementation) on random graphs."""
+    """Acceptance: the single-dispatch scan returns identical parts_u and
+    sets to the per-block host loop (seed implementation) on random
+    graphs.  No row passes cap, so every block runs in compact space;
+    blocks of a size k does not divide enter with unequal sizes (the
+    catch-up round), and the last block is ragged."""
     g = _random_graph(seed)
-    want = blocked_partition_u_hostloop(g, k, block=block, use_kernel=False,
-                                        seed=seed)
-    got = blocked_partition_u(g, k, block=block, use_kernel=False, seed=seed)
+    assert not pack_graph_blocks(g, block).trunc.any()
+    want, s_want = blocked_partition_u_hostloop(
+        g, k, block=block, use_kernel=False, seed=seed, return_sets=True)
+    got, s_got = blocked_partition_u(g, k, block=block, use_kernel=False,
+                                     seed=seed, return_sets=True)
     assert np.array_equal(got, want)
+    assert np.array_equal(s_got, s_want)
 
 
 def test_scan_pipeline_matches_hostloop_kernel_path():
@@ -243,6 +256,113 @@ def test_scan_pipeline_matches_hostloop_trunc_fallback():
     got = blocked_partition_u(g, 4, block=128, use_kernel=False, seed=0,
                               cap=3)
     assert np.array_equal(got, want)
+
+
+def _hub_graph(seed, nu=640, nv=4096, hubs=3, hub_len=400):
+    """Rows of ≤ 6 ids, plus ``hubs`` rows of ``hub_len`` ids: at cap 8
+    the blocks that draw a hub hold a truncated row, the others none."""
+    rng = np.random.default_rng(seed)
+    lens = rng.integers(1, 7, nu)
+    lens[rng.choice(nu, hubs, replace=False)] = hub_len
+    u = np.repeat(np.arange(nu), lens)
+    return from_edges(nu, nv, u, rng.integers(0, nv, u.shape[0]))
+
+
+@pytest.mark.parametrize("backend", ["device_scan", "parallel_device"])
+def test_scan_pipeline_matches_hostloop_mixed_blocks(backend):
+    """Blocks with and without a truncated row in one scan: the dense and
+    the compact branch hand (S, sizes) to each other, and the result is
+    the host loop's, parts and sets."""
+    g = _hub_graph(7)
+    k, block, cap, seed = 4, 64, 8, 1
+    order = np.random.default_rng(seed).permutation(g.num_u)
+    has_trunc = pack_graph_blocks(g, block, order=order,
+                                  cap=cap).trunc.any(axis=1)
+    assert has_trunc.any() and not has_trunc.all()
+    want, s_want = blocked_partition_u_hostloop(
+        g, k, block=block, use_kernel=False, seed=seed, return_sets=True)
+    if backend == "device_scan":
+        got, s_got = blocked_partition_u(g, k, block=block, use_kernel=False,
+                                         seed=seed, cap=cap, return_sets=True)
+    else:
+        got, s_got, _ = parallel_blocked_partition_u_impl(
+            g, k, workers=1, block=block, seed=seed, cap=cap)
+    assert np.array_equal(got, want)
+    assert np.array_equal(s_got, s_want)
+
+
+@pytest.mark.parametrize("k", [4, 16])
+def test_compact_block_matches_assign_block(k):
+    """One block with no truncated row, ragged (padding rows), entering
+    with unequal sizes and non-empty sets: the rounds equal the per-vertex
+    reference ``_assign_block``, parts, sets and sizes."""
+    rng = np.random.default_rng(k)
+    B, num_v, cap = 96, 3000, 48
+    rows = [rng.choice(num_v, int(rng.integers(1, 30)), replace=False)
+            for _ in range(B - 11)]
+    g = from_edges(len(rows), num_v, np.repeat(np.arange(len(rows)),
+                                               [len(r) for r in rows]),
+                   np.concatenate(rows))
+    packed = pack_graph_blocks(g, B, order=np.arange(g.num_u), cap=cap)
+    assert not packed.trunc.any() and not packed.valid.all()
+    s0 = pack_bitmask(rng.random((k, num_v)) < 0.05, num_v)
+    sz0 = jnp.asarray(rng.integers(0, 2, k) + 7, jnp.int32)
+    valid = jnp.asarray(packed.valid[0])
+    rounds = jax.jit(functools.partial(
+        _assign_block_rounds, k=k, use_kernel=False, interpret=None))
+    got = rounds(valid, jnp.asarray(packed.widx[0]),
+                 jnp.asarray(packed.vals[0]), jnp.asarray(packed.trunc[0]),
+                 jnp.asarray(packed.overflow_spans[0]),
+                 jnp.asarray(packed.overflow_words), jnp.asarray(s0), sz0)
+    nbr = pack_bitmask([g.neighbors(u) for u in range(g.num_u)]
+                       + [[]] * (B - g.num_u), num_v)
+    want = _assign_block(jnp.asarray(nbr), jnp.asarray(s0), sz0, valid,
+                         k=k, use_kernel=False)
+    for a, b in zip(got, want):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+def _largest_intermediate(jaxpr) -> int:
+    """Elements of the largest value any equation of ``jaxpr`` (and of
+    every jaxpr nested in its equations' parameters) produces."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+
+    def nested(p):
+        if isinstance(p, ClosedJaxpr):
+            yield p.jaxpr
+        elif isinstance(p, Jaxpr):
+            yield p
+        elif isinstance(p, (tuple, list)):
+            for q in p:
+                yield from nested(q)
+
+    most = 0
+    for eqn in jaxpr.eqns:
+        for v in eqn.outvars:
+            most = max(most, int(np.prod(v.aval.shape, dtype=np.int64)))
+        for p in eqn.params.values():
+            for sub in nested(p):
+                most = max(most, _largest_intermediate(sub))
+    return most
+
+
+def test_compact_block_builds_no_dense_mask():
+    """At ``criteo_k16``'s widths the compact branch never holds a (B, W)
+    or (W, B) array: nothing it computes is larger than the (k, W) sets.
+    The dense branch, traced the same way, does build its mask."""
+    B, cap, W, k, L = 256, 48, 131072, 16, 1 << 17
+
+    def spec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    block = (spec((B,), jnp.bool_), spec((B, cap)), spec((B, cap)))
+    sets = (spec((k, W)), spec((k,)))
+    compact = jax.make_jaxpr(functools.partial(_compact_block_rounds, k=k))(
+        *block, *sets)
+    assert _largest_intermediate(compact.jaxpr) <= k * W
+    dense = jax.make_jaxpr(functools.partial(_dense_block_rounds, k=k))(
+        *block, spec((B,), jnp.bool_), spec((2,)), spec((3, L)), *sets)
+    assert _largest_intermediate(dense.jaxpr) >= B * W
 
 
 def test_scan_pipeline_matches_hostloop_init_sets():

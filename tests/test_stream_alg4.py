@@ -117,7 +117,8 @@ def test_alg4_merge_counters_match_formulas_and_traffic(alg4):
         assert c["pushed_words"] * 4 == f["pushed_bytes"] > 0
         assert f["tasks"] == workers * rounds
         # the packed blocks' counts are there as on one chip
-        assert {"upload_bytes", "channel_rows", "channel_slots"} <= set(c)
+        assert {"upload_bytes", "channel_rows", "channel_slots",
+                "compact_blocks"} <= set(c)
 
 
 def test_alg4_phases_and_merge_counters_in_the_trace(alg4):
@@ -129,6 +130,8 @@ def test_alg4_phases_and_merge_counters_in_the_trace(alg4):
     assert {m: spans["parsa.feed.wait"][m] for m in merge} == {
         m: last["counters"][m] for m in merge}
     assert not set(merge) & set(spans["parsa.feed.pack"])
+    assert spans["parsa.feed.pack"]["compact_blocks"] == last["counters"][
+        "compact_blocks"]
 
 
 def test_alg4_worker_shards_sit_on_their_own_devices(alg4):

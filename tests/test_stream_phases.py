@@ -86,6 +86,22 @@ def test_feed_counters_count_the_packed_blocks():
     assert upd.counters["channel_words"] <= upd.counters["channel_slots"]
     assert upd.counters["channel_grew"] == 1     # the first feed sets it
     assert sess.feed(g).counters["channel_grew"] == 0
+    # every block holds a row past cap: none runs in compact space
+    assert packed.trunc.any(axis=1).all()
+    assert upd.counters["compact_blocks"] == 0
+
+
+def test_feed_counters_count_compact_blocks():
+    """Click-log rows of no more words than cap: every block of the feed
+    is one the scan runs in compact space."""
+    chunks = ctr_like_stream(1000, 1 << 14, chunks=2, nnz_per_row=CAP,
+                             seed=4)
+    sess = _session(1 << 14, repartition="never")
+    for ch in chunks:
+        upd = sess.feed(ch)
+        blocks = -(-ch.num_u // 64)
+        assert upd.counters["channel_rows"] == 0
+        assert upd.counters["compact_blocks"] == blocks
 
 
 def test_feed_spans_land_in_the_profiler_trace(tmp_path):

@@ -1,6 +1,6 @@
 """Compile the Pallas kernels of the partition path, the kernel-path
-scan, and the Alg 4 program across a 2x2 mesh, for a described TPU v5e
-at deployment widths — no chip needed.
+and jnp-path scans, and the Alg 4 program across a 2x2 mesh, for a
+described TPU v5e at deployment widths — no chip needed.
 
 Interpret mode accepts what Mosaic refuses (dynamic slices of vectors,
 scoped-VMEM overflow), so only these compiles show that the kernels run on
@@ -122,6 +122,21 @@ def test_kernel_path_partition_scan_compiles(one_chip):
     text = _partition_scan.lower(*args, k=k, use_kernel=True,
                                  interpret=False).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+def test_jnp_path_partition_scan_compiles(one_chip):
+    """A few blocks of ``ctr.stream``'s program: the jnp path's scan at
+    ``criteo_k16``'s widths, each block choosing on device between the
+    compact-space greedy and the densified one."""
+    nb, b, cap, slots, k = 4, 256, 48, 1 << 17, 16
+    args = (_i32((nb, b), one_chip, jnp.bool_), _i32((nb, b, cap), one_chip),
+            _i32((nb, b, cap), one_chip), _i32((nb, b), one_chip, jnp.bool_),
+            _i32((nb, 2), one_chip), _i32((3, slots), one_chip),
+            _i32((k, W), one_chip), _i32((k,), one_chip))
+    text = _partition_scan.lower(*args, k=k, use_kernel=False,
+                                 interpret=False).compile().as_text()
+    assert text.startswith("HloModule jit__partition_scan")
+    assert "conditional(" in text
 
 
 def test_parallel_partition_scan_compiles_for_four_chips(topo):
