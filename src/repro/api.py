@@ -17,11 +17,9 @@ the one place it is exposed:
 Backends (``host``, ``device_scan``, ``host_blocked_oracle``,
 ``parallel_sim``, ``parallel_device``) live in the registry in
 ``repro.api_backends``; add a strategy with ``@register_backend`` instead
-of a new module-level function.
-The five pre-facade entry points (``partition_u``, ``sequential_parsa``,
-``ParallelParsa.run``, ``blocked_partition_u``,
-``blocked_partition_u_hostloop``) remain as deprecation-warning shims that
-delegate here and return bit-identical results.
+of a new module-level function.  This is the one entry point: the
+``_impl`` functions under ``repro.core`` are the registered
+implementations, not a second public door.
 
 ``PartitionResult`` uniformly carries the final neighbor sets as packed
 bitmasks (``s_masks``, (k, ceil(|V|/32)) int32) with a lazy dense bool view

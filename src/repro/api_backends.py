@@ -10,7 +10,7 @@ uniform signature ``fn(graph, config, init_sets=None) -> BackendOutput``:
     jitted ``lax.scan`` over packed bitmask blocks, fused cost+select
     (``blocked_partition_u_impl``).
   * ``host_blocked_oracle`` — the seed per-block host loop, kept as the
-    parity oracle and benchmark baseline.
+    test reference ``device_scan`` must match bit for bit.
   * ``parallel_sim``        — the deterministic Alg 4 parameter-server
     simulation with W workers and bounded delay τ, on the packed-word wire
     format; fills ``BackendOutput.traffic``.
@@ -176,7 +176,7 @@ def device_scan_backend(graph: BipartiteGraph, config, init_sets=None) -> Backen
 
 @register_backend("host_blocked_oracle")
 def host_blocked_oracle_backend(graph: BipartiteGraph, config, init_sets=None) -> BackendOutput:
-    """Seed per-block host loop — the parity oracle for ``device_scan``."""
+    """Seed per-block host loop — the test reference for ``device_scan``."""
     parts_u, s_masks = blocked_partition_u_hostloop_impl(
         graph, config.k, block=config.block_size, init_sets=init_sets,
         use_kernel=config.use_kernel, interpret=config.interpret,

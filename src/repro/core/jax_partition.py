@@ -5,9 +5,9 @@ The CPU algorithm's O(1) pointer updates don't map to TPU; instead we
 assigned by repeatedly picking the partition to grow (smallest size, Alg 1
 line 7 / §4.1 perfect balance) and the minimum-cost unassigned vertex
 within the block for it.  Block-local greedy is a sampling approximation in
-exactly the sense of §4.2 (a block plays the role of a subgraph R); quality
-deltas vs the sequential reference are measured in
-benchmarks/bench_table2.py.
+exactly the sense of §4.2 (a block plays the role of a subgraph R); its
+reference is the per-vertex block loop of the ``host_blocked_oracle``
+backend (``blocked_partition_u_hostloop_impl``).
 
 Dispatch model (one scan, donated carries, fused select)
 --------------------------------------------------------
@@ -31,11 +31,6 @@ The pipeline is fully device-resident:
    ``(S, sizes)`` carries donated, instead of one host dispatch per block.
    ``dispatch_counter()`` observes exactly one launch per partition call.
 
-This module's public names are deprecation shims over the ``repro.api``
-facade (backends ``device_scan`` / ``host_blocked_oracle``); the ``_impl``
-functions are the registered implementations and also return the final
-packed ``s_masks`` so the device path warm-starts with host-path parity.
-
 3. *Greedy rounds + fused select* — perfect balance makes the partition
    visit order deterministic: when partition sizes differ by at most one
    (always true here: sizes start equal and every round preserves it), the
@@ -56,13 +51,15 @@ packed ``s_masks`` so the device path warm-starts with host-path parity.
    — bit-exact either way.
 
    Both paths produce *identical* assignments to the sequential per-vertex
-   reference ``blocked_partition_u_hostloop`` (property-tested), because a
-   round's selections see exactly the tile state the per-vertex loop would:
-   within a round each column is picked at most once, down-dates touch only
-   the picked column, and cross-column interaction is pure retirement.
+   reference ``blocked_partition_u_hostloop_impl`` (property-tested),
+   because a round's selections see exactly the tile state the per-vertex
+   loop would: within a round each column is picked at most once,
+   down-dates touch only the picked column, and cross-column interaction
+   is pure retirement.
 
-``shard_parsa_step`` maps Alg 4 onto shard_map: each device on the ``data``
-axis partitions its own U-shard block-by-block against a device-local
+``_parallel_scan_fn`` builds the one Alg 4 program
+(``_parallel_partition_scan``), a shard_map over the worker mesh: each
+worker partitions its own share of the blocks against a device-local
 *stale* bitmask copy; every ``merge_every`` blocks an all_gather + OR
 merges the sets — the bulk-synchronous image of the parameter server's
 union-push (server line 9), with τ == merge_every − 1 blocks of staleness.
@@ -73,7 +70,6 @@ import contextlib
 import dataclasses
 import functools
 import time
-import warnings
 from typing import NamedTuple
 
 import jax
@@ -95,12 +91,9 @@ from ..kernels.parsa_cost import (
 from .bipartite import BipartiteGraph
 
 __all__ = [
-    "blocked_partition_u",
-    "blocked_partition_u_hostloop",
     "blocked_partition_u_impl",
     "blocked_partition_u_hostloop_impl",
     "parallel_blocked_partition_u_impl",
-    "shard_parsa_step",
     "pack_graph_blocks",
     "PackedBlocks",
     "dispatch_counter",
@@ -220,6 +213,16 @@ class PackedBlocks(NamedTuple):
     order: np.ndarray     # (num_u,) int64 — global vertex id per packed row
 
 
+def _upload_blocks(packed: PackedBlocks) -> tuple[jax.Array, ...]:
+    """The six per-block arrays ``_partition_scan`` takes, in its argument
+    order, each put on the default device by ``jnp.asarray``: the one
+    place the scan's block layout is spelled out for a one-chip call
+    (``_place_parallel_blocks`` places the same six on a worker mesh)."""
+    return tuple(jnp.asarray(x) for x in (
+        packed.valid, packed.widx, packed.vals, packed.trunc,
+        packed.overflow_spans, packed.overflow_words))
+
+
 def _overflow_slots(words: int, floor: int, slots: int) -> int:
     """Capacity L of the overflow-word list for a feed of ``words``
     entries: ``slots`` (the capacity already compiled) while the feed fits
@@ -295,7 +298,7 @@ def pack_graph_blocks(
 
 # --------------------------------------------------------------------------
 # Sequential per-vertex reference (the seed implementation, kept as the
-# parity oracle and benchmark baseline).
+# parity oracle the tests hold the scan to).
 # --------------------------------------------------------------------------
 @functools.partial(jax.jit, static_argnames=("k", "use_kernel", "interpret"))
 def _assign_block(
@@ -703,10 +706,7 @@ def blocked_partition_u_impl(
                     nbytes=int(s_masks.nbytes) + int(sizes.nbytes),
                     k=k, blocks=int(packed.valid.shape[0]))
     parts_blocks, s_out, _ = _partition_scan(
-        jnp.asarray(packed.valid), jnp.asarray(packed.widx),
-        jnp.asarray(packed.vals), jnp.asarray(packed.trunc),
-        jnp.asarray(packed.overflow_spans),
-        jnp.asarray(packed.overflow_words), s_masks, sizes,
+        *_upload_blocks(packed), s_masks, sizes,
         k=k, use_kernel=use_kernel, interpret=interpret, sketch=sketch)
     if not as_numpy:
         flat = parts_blocks.reshape(-1)[: graph.num_u]
@@ -719,35 +719,6 @@ def blocked_partition_u_impl(
     return parts, np.asarray(s_out)
 
 
-def blocked_partition_u(
-    graph: BipartiteGraph,
-    k: int,
-    block: int = 256,
-    init_sets: np.ndarray | None = None,
-    use_kernel: bool = True,
-    interpret: bool | None = None,
-    seed: int = 0,
-    cap: int = 48,
-    return_sets: bool = False,
-) -> np.ndarray | tuple[np.ndarray, np.ndarray]:
-    """Deprecated shim — use ``repro.api.partition`` with
-    ``backend="device_scan"``.  Returns parts_u (bit-identical to the
-    pre-facade output); with ``return_sets=True`` also the final packed
-    ``s_masks`` for warm-start parity with the host path."""
-    warnings.warn(
-        "blocked_partition_u is deprecated; use repro.api.partition(graph, "
-        "ParsaConfig(k=..., backend='device_scan', block_size=...))",
-        DeprecationWarning, stacklevel=2)
-    from ..api import ParsaConfig
-    from ..api_backends import get_backend
-
-    cfg = ParsaConfig(k=k, backend="device_scan", block_size=block,
-                      cap=cap, use_kernel=use_kernel, interpret=interpret,
-                      seed=seed, refine_v=False)
-    out = get_backend(cfg.backend)(graph, cfg, init_sets=init_sets)
-    return (out.parts_u, out.s_masks) if return_sets else out.parts_u
-
-
 def blocked_partition_u_hostloop_impl(
     graph: BipartiteGraph,
     k: int,
@@ -758,8 +729,8 @@ def blocked_partition_u_hostloop_impl(
     seed: int = 0,
 ) -> tuple[np.ndarray, np.ndarray]:
     """The seed implementation: per-block Python packing + one dispatch per
-    block + per-vertex greedy.  Kept verbatim as the parity oracle and the
-    benchmark baseline for the single-dispatch pipeline.
+    block + per-vertex greedy.  Kept verbatim as the test reference that
+    the single-dispatch pipeline must match bit for bit.
     Returns (parts_u, final packed s_masks)."""
     W = (graph.num_v + 31) // 32
     if init_sets is None:
@@ -779,32 +750,6 @@ def blocked_partition_u_hostloop_impl(
         )
         parts[ids] = np.asarray(p)
     return parts, np.asarray(s_masks)
-
-
-def blocked_partition_u_hostloop(
-    graph: BipartiteGraph,
-    k: int,
-    block: int = 256,
-    init_sets: np.ndarray | None = None,
-    use_kernel: bool = True,
-    interpret: bool | None = None,
-    seed: int = 0,
-    return_sets: bool = False,
-) -> np.ndarray | tuple[np.ndarray, np.ndarray]:
-    """Deprecated shim — use ``repro.api.partition`` with
-    ``backend="host_blocked_oracle"``."""
-    warnings.warn(
-        "blocked_partition_u_hostloop is deprecated; use repro.api.partition("
-        "graph, ParsaConfig(k=..., backend='host_blocked_oracle'))",
-        DeprecationWarning, stacklevel=2)
-    from ..api import ParsaConfig
-    from ..api_backends import get_backend
-
-    cfg = ParsaConfig(k=k, backend="host_blocked_oracle", block_size=block,
-                      use_kernel=use_kernel, interpret=interpret, seed=seed,
-                      refine_v=False)
-    out = get_backend(cfg.backend)(graph, cfg, init_sets=init_sets)
-    return (out.parts_u, out.s_masks) if return_sets else out.parts_u
 
 
 def _pad_block_stack(packed: PackedBlocks, n_total: int) -> PackedBlocks:
@@ -1185,48 +1130,3 @@ def parallel_blocked_partition_u_impl(
     parts[order] = flat
     return parts, np.asarray(s_out), traffic
 
-
-def shard_parsa_step(k: int, axis: str = "data", use_kernel: bool = False,
-                     select: str = "rounds", interpret: bool | None = None):
-    """Return a shard_map-able body: (local packed block stack, S, sizes) →
-    assignment.
-
-    Each device processes its (n_blocks, B, …) stack (from
-    ``pack_graph_blocks`` on its U-shard) against its local S copy, then
-    merges S across ``axis`` by all_gather + OR and sizes by psum — one
-    Alg 4 round with τ = n_blocks − 1.
-
-    ``select="rounds"`` uses the balanced-rounds pipeline (fused
-    cost+select; exact vs the sequential loop while global sizes differ by
-    ≤ 1, and a balanced approximation thereof once cross-device psums widen
-    the gap).  ``select="seq"`` keeps the per-vertex reference loop.
-    """
-
-    def body(valid: jax.Array, widx: jax.Array, vals: jax.Array,
-             trunc: jax.Array, spans: jax.Array, overflow: jax.Array,
-             s_masks: jax.Array, sizes: jax.Array):
-        def per_block(carry, xs):
-            s_masks, sizes = carry
-            val, wi, va, tr, sp = xs
-            if select == "rounds":
-                parts, s_masks, sizes = _assign_block_rounds(
-                    val, wi, va, tr, sp, overflow, s_masks, sizes,
-                    k=k, use_kernel=use_kernel, interpret=interpret)
-            else:
-                parts, s_masks, sizes = _assign_block(
-                    _rebuild_nbr(wi, va, sp, overflow, s_masks.shape[1]),
-                    s_masks, sizes, val,
-                    k=k, use_kernel=use_kernel, interpret=interpret)
-            return (s_masks, sizes), parts
-
-        (s_masks, sizes), parts = jax.lax.scan(
-            per_block, (s_masks, sizes), (valid, widx, vals, trunc, spans))
-        # server union-push: OR-merge neighbor sets across the data axis
-        gathered = jax.lax.all_gather(s_masks, axis)  # (n_dev, k, W)
-        merged = jax.lax.reduce(
-            gathered, jnp.int32(0), jax.lax.bitwise_or, dimensions=(0,)
-        )
-        sizes = jax.lax.psum(sizes, axis)
-        return parts, merged, sizes
-
-    return body

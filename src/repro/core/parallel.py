@@ -33,7 +33,6 @@ the same protocol (bitmask all-reduce OR == server union) is the
 from __future__ import annotations
 
 import dataclasses
-import warnings
 
 import numpy as np
 
@@ -48,8 +47,7 @@ from .costs import need_matrix
 from .partition_u import partition_u_impl
 from .subgraphs import divide
 
-__all__ = ["ParallelParsa", "ParsaReport", "global_initialization",
-           "parallel_parsa_impl"]
+__all__ = ["ParsaReport", "global_initialization", "parallel_parsa_impl"]
 
 
 @dataclasses.dataclass
@@ -188,51 +186,3 @@ def parallel_parsa_impl(
                          len(schedule), missed)
     return report, S_server
 
-
-class ParallelParsa:
-    """Deterministic simulation of Alg 4 with W workers and max delay τ.
-
-    Deprecated shim — use ``repro.api.partition`` with
-    ``backend="parallel_sim"``; ``run`` delegates to the backend registry.
-    ``parts_u`` is bit-identical to the pre-facade implementation; the
-    traffic counters use the PR-3 packed-word units (see ``ParsaReport``)."""
-
-    def __init__(
-        self,
-        k: int,
-        workers: int = 4,
-        tau: int | None = 0,
-        theta: int = 1000,
-        select: str = "size",
-        seed: int = 0,
-    ):
-        self.k = k
-        self.workers = workers
-        self.tau = tau
-        self.theta = theta
-        self.select = select
-        self.seed = seed
-
-    def run(
-        self,
-        graph: BipartiteGraph,
-        b: int,
-        a: int = 0,
-        init_sets: np.ndarray | None = None,
-    ) -> ParsaReport:
-        warnings.warn(
-            "ParallelParsa.run is deprecated; use repro.api.partition(graph, "
-            "ParsaConfig(k=..., backend='parallel_sim', blocks=b, "
-            "init_iters=a, workers=..., tau=...))",
-            DeprecationWarning, stacklevel=2)
-        from ..api import ParsaConfig
-        from ..api_backends import get_backend
-
-        cfg = ParsaConfig(
-            k=self.k, backend="parallel_sim", blocks=b, init_iters=a,
-            workers=self.workers, tau=self.tau, theta=self.theta,
-            select=self.select, seed=self.seed, refine_v=False)
-        out = get_backend(cfg.backend)(graph, cfg, init_sets=init_sets)
-        t = out.traffic
-        return ParsaReport(out.parts_u, t.pushed_bytes, t.pulled_bytes,
-                           t.tasks, t.stale_pushes_missed)

@@ -21,45 +21,18 @@ pair is touched at most once ⇒ O(k|E|).
 """
 from __future__ import annotations
 
-import warnings
-
 import numpy as np
 
 from .bipartite import BipartiteGraph
 from .bucket_queue import BucketQueue
 
-__all__ = ["partition_u", "partition_u_impl", "PartitionUResult"]
+__all__ = ["partition_u_impl", "PartitionUResult"]
 
 
 class PartitionUResult:
     def __init__(self, parts_u: np.ndarray, neighbor_sets: np.ndarray):
         self.parts_u = parts_u          # (|U|,) int32
         self.neighbor_sets = neighbor_sets  # (k, |V|) bool — updated S_i
-
-
-def partition_u(
-    graph: BipartiteGraph,
-    k: int,
-    init_sets: np.ndarray | None = None,
-    theta: int = 1000,
-    select: str = "size",
-    seed: int = 0,
-) -> PartitionUResult:
-    """Deprecated shim — use ``repro.api.partition`` with ``backend="host"``.
-
-    Delegates to the backend registry; output is bit-identical to the
-    pre-facade implementation (``partition_u_impl``)."""
-    warnings.warn(
-        "repro.core.partition_u is deprecated; use repro.api.partition("
-        "graph, ParsaConfig(k=..., backend='host'))",
-        DeprecationWarning, stacklevel=2)
-    from ..api import ParsaConfig
-    from ..api_backends import get_backend
-
-    cfg = ParsaConfig(k=k, backend="host", theta=theta, select=select,
-                      seed=seed, refine_v=False)
-    out = get_backend(cfg.backend)(graph, cfg, init_sets=init_sets)
-    return PartitionUResult(out.parts_u, out.neighbor_sets)
 
 
 def partition_u_impl(

@@ -16,7 +16,6 @@ Initialization (§4.4):
 from __future__ import annotations
 
 import dataclasses
-import warnings
 
 import numpy as np
 
@@ -24,7 +23,7 @@ from .bipartite import BipartiteGraph
 from .costs import need_matrix
 from .partition_u import partition_u_impl
 
-__all__ = ["divide", "sequential_parsa", "sequential_parsa_impl", "SubgraphPlan"]
+__all__ = ["divide", "sequential_parsa_impl", "SubgraphPlan"]
 
 
 @dataclasses.dataclass
@@ -40,31 +39,6 @@ def divide(graph: BipartiteGraph, b: int, seed: int = 0) -> SubgraphPlan:
     perm = rng.permutation(graph.num_u)
     blocks = [np.sort(x) for x in np.array_split(perm, b)]
     return SubgraphPlan(blocks, [graph.subgraph_u(blk) for blk in blocks])
-
-
-def sequential_parsa(
-    graph: BipartiteGraph,
-    k: int,
-    b: int = 16,
-    a: int = 0,
-    theta: int = 1000,
-    select: str = "size",
-    seed: int = 0,
-    init_sets: np.ndarray | None = None,
-) -> np.ndarray:
-    """Deprecated shim — use ``repro.api.partition`` with ``backend="host"``
-    and ``blocks=b`` / ``init_iters=a``.  Output is bit-identical to the
-    pre-facade implementation (``sequential_parsa_impl``)."""
-    warnings.warn(
-        "repro.core.sequential_parsa is deprecated; use repro.api.partition("
-        "graph, ParsaConfig(k=..., backend='host', blocks=b, init_iters=a))",
-        DeprecationWarning, stacklevel=2)
-    from ..api import ParsaConfig
-    from ..api_backends import get_backend
-
-    cfg = ParsaConfig(k=k, backend="host", blocks=b, init_iters=a,
-                      theta=theta, select=select, seed=seed, refine_v=False)
-    return get_backend(cfg.backend)(graph, cfg, init_sets=init_sets).parts_u
 
 
 def sequential_parsa_impl(

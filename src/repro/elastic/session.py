@@ -39,6 +39,7 @@ from ..core.bipartite import BipartiteGraph
 from ..core.jax_partition import (
     _count_dispatch,
     _partition_scan,
+    _upload_blocks,
     pack_graph_blocks,
 )
 from ..core.parallel import global_initialization
@@ -292,10 +293,7 @@ class ElasticSession:
                         nbytes=int(packed.valid.nbytes), rows=int(rows.size),
                         machine=int(src))
         parts2, m2, _ = _partition_scan(
-            jnp.asarray(packed.valid), jnp.asarray(packed.widx),
-            jnp.asarray(packed.vals), jnp.asarray(packed.trunc),
-            jnp.asarray(packed.overflow_spans),
-            jnp.asarray(packed.overflow_words),
+            *_upload_blocks(packed),
             jnp.zeros((2, arena.W_cap), jnp.int32),
             jnp.zeros((2,), jnp.int32),
             k=2, use_kernel=base.use_kernel, interpret=base.interpret,
@@ -454,10 +452,7 @@ class ElasticSession:
                         nbytes=int(packed.valid.nbytes), rows=int(rows.size),
                         machine=int(machine))
         parts_sub, s_out, sz_out = _partition_scan(
-            jnp.asarray(packed.valid), jnp.asarray(packed.widx),
-            jnp.asarray(packed.vals), jnp.asarray(packed.trunc),
-            jnp.asarray(packed.overflow_spans),
-            jnp.asarray(packed.overflow_words),
+            *_upload_blocks(packed),
             jnp.asarray(masks), jnp.asarray(sizes_live),
             k=k, use_kernel=base.use_kernel, interpret=base.interpret,
             sketch=self.stream.sketch is not None)

@@ -264,10 +264,10 @@ def pack_bitmask_csr_sparse(
     One argsort over (row, word) keys yields both representations without
     ever touching a dense (n, W) array — the caller chooses where (and
     whether) to densify: ``pack_bitmask_csr_compact`` scatters on the host,
-    while ``blocked_partition_u`` never densifies globally at all — it
-    ships only the compact lists plus the truncated rows' overflow words
-    (the entries at ``pos >= cap``) and rebuilds each block's (B, W)
-    bitmask on device inside the scan.
+    while the device scan (``pack_graph_blocks``) never densifies globally
+    at all — it ships only the compact lists plus the truncated rows'
+    overflow words (the entries at ``pos >= cap``) and rebuilds a block's
+    (B, W) bitmask on device inside the scan where it needs one.
 
     Returns (uniq (nnz,) int64 flat indices into the (n, W) mask,
     wordvals (nnz,) int32, widx (n, cap) int32, vals (n, cap) int32,

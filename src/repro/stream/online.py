@@ -55,6 +55,7 @@ from ..core.jax_partition import (
     _parallel_traffic,
     _partition_scan,
     _place_parallel_blocks,
+    _upload_blocks,
     blocked_partition_u_impl,
     pack_graph_blocks,
     parallel_blocked_partition_u_impl,
@@ -146,14 +147,14 @@ _PHASE_SUMS = ("partition_u", "total")
 
 def _packed_counters(packed, grew: bool) -> dict[str, int]:
     """Per-feed counts of the packed blocks a scan is given: the bytes of
-    the six arrays put on the device; the truncated rows, the overflow
-    words they carry and the overflow list's capacity; whether this feed
-    raised that capacity (a new shape: the scan compiles); and the blocks
-    with no truncated row, which the jnp scan runs in compact space."""
-    arrays = (packed.valid, packed.widx, packed.vals, packed.trunc,
-              packed.overflow_spans, packed.overflow_words)
+    the arrays put on the device (all but ``order``, which stays on the
+    host); the truncated rows, the overflow words they carry and the
+    overflow list's capacity; whether this feed raised that capacity (a
+    new shape: the scan compiles); and the blocks with no truncated row,
+    which the jnp scan runs in compact space."""
     spans = packed.overflow_spans
-    return {"upload_bytes": sum(int(x.nbytes) for x in arrays),
+    return {"upload_bytes": (sum(int(x.nbytes) for x in packed)
+                             - int(packed.order.nbytes)),
             "channel_rows": int(packed.trunc.sum()),
             "channel_words": int((spans[:, 1] - spans[:, 0]).sum()),
             "channel_slots": int(packed.overflow_words.shape[1]),
@@ -265,8 +266,6 @@ class StreamSession:
         attributes of the ``pack`` span, and on Alg 4 feeds
         ``_merge_counters``'s too, also attributes of the ``wait`` span.
         """
-        import jax.numpy as jnp
-
         from ..core.jax_partition import dispatch_counter
 
         base = self.config.base
@@ -306,11 +305,7 @@ class StreamSession:
                         nbytes=(int(self.arena.s_masks.nbytes)
                                 + int(self.arena.sizes.nbytes)),
                         k=self.k)
-                    blocks = (
-                        jnp.asarray(packed.valid), jnp.asarray(packed.widx),
-                        jnp.asarray(packed.vals), jnp.asarray(packed.trunc),
-                        jnp.asarray(packed.overflow_spans),
-                        jnp.asarray(packed.overflow_words))
+                    blocks = _upload_blocks(packed)
                 with step("launch"):
                     parts_blocks, s_out, sz_out = _partition_scan(
                         *blocks, self.arena.s_masks, self.arena.sizes,

@@ -1,7 +1,6 @@
 """The unified repro.api facade: config validation, backend registry,
-result uniformity, warm-start refine, and bit-exact parity between the
-five legacy entry points (now deprecation shims) and their pre-refactor
-implementations."""
+result uniformity, warm-start refine, and bit-exact parity between each
+registered strategy and the implementation it wraps."""
 import numpy as np
 import pytest
 
@@ -16,7 +15,7 @@ from repro.graphs import text_like
 
 @pytest.fixture(scope="module")
 def parity_graph():
-    """Fixed-seed 2k-vertex graph for the shim parity acceptance test."""
+    """Fixed-seed 2k-vertex graph for the registry parity test."""
     return text_like(2000, 3000, mean_len=20, seed=42)
 
 
@@ -159,134 +158,70 @@ def test_unknown_backend_at_partition_time(small_graph):
         ParsaConfig(k=4).replace(backend="also-nope")
 
 
-# --------------------------------------------------- legacy shims: warnings
-def test_legacy_shims_emit_deprecation_warnings(small_graph):
-    from repro.core.jax_partition import (
-        blocked_partition_u, blocked_partition_u_hostloop)
-    from repro.core.parallel import ParallelParsa
-    from repro.core.partition_u import partition_u
-    from repro.core.subgraphs import sequential_parsa
+# ------------------------------------------- registry → _impl exact parity
+# Each registered strategy, reached through ``partition``, returns results
+# bit-identical to its implementation called directly on a fixed-seed
+# 2k-vertex graph: the config → implementation plumbing adds nothing.
+def _host_impl(g):
+    from repro.core.partition_u import partition_u_impl
 
-    g = small_graph
-    with pytest.warns(DeprecationWarning, match="partition_u is deprecated"):
-        partition_u(g, 4)
-    with pytest.warns(DeprecationWarning, match="sequential_parsa is deprecated"):
-        sequential_parsa(g, 4, b=2, a=0)
-    with pytest.warns(DeprecationWarning, match="ParallelParsa.run is deprecated"):
-        ParallelParsa(4, workers=2, tau=0).run(g, b=2)
-    with pytest.warns(DeprecationWarning, match="blocked_partition_u is deprecated"):
-        blocked_partition_u(g, 4, block=64, use_kernel=False)
-    with pytest.warns(DeprecationWarning,
-                      match="blocked_partition_u_hostloop is deprecated"):
-        blocked_partition_u_hostloop(g, 4, block=64, use_kernel=False)
+    ref = partition_u_impl(g, 8, seed=3)
+    return ref.parts_u, ref.neighbor_sets, None
 
 
-def test_legacy_shims_warn_exactly_once_and_match_registry(small_graph):
-    """Each of the five legacy entry points emits its DeprecationWarning
-    exactly ONCE per call (no double-warn through the delegation chain) and
-    still returns what the backend registry returns."""
-    import warnings
+def _host_blocks_impl(g):
+    from repro.core.subgraphs import sequential_parsa_impl
 
-    from repro.api_backends import get_backend
-    from repro.core.jax_partition import (
-        blocked_partition_u, blocked_partition_u_hostloop)
-    from repro.core.parallel import ParallelParsa
-    from repro.core.partition_u import partition_u
-    from repro.core.subgraphs import sequential_parsa
-
-    g, k = small_graph, 4
-
-    def once(fn):
-        with warnings.catch_warnings(record=True) as rec:
-            warnings.simplefilter("always")
-            out = fn()
-        dep = [w for w in rec if issubclass(w.category, DeprecationWarning)]
-        assert len(dep) == 1, [str(w.message) for w in dep]
-        return out
-
-    res = once(lambda: partition_u(g, k))
-    want = get_backend("host")(g, ParsaConfig(k=k))
-    assert np.array_equal(res.parts_u, want.parts_u)
-
-    got = once(lambda: sequential_parsa(g, k, b=2, a=0, seed=1))
-    want = get_backend("host")(g, ParsaConfig(k=k, blocks=2, seed=1))
-    assert np.array_equal(got, want.parts_u)
-
-    rep = once(lambda: ParallelParsa(k, workers=2, tau=0, seed=2)
-               .run(g, b=2))
-    want = get_backend("parallel_sim")(
-        g, ParsaConfig(k=k, blocks=2, workers=2, tau=0, seed=2))
-    assert np.array_equal(rep.parts_u, want.parts_u)
-
-    got = once(lambda: blocked_partition_u(g, k, block=64, use_kernel=False,
-                                           seed=3))
-    want = get_backend("device_scan")(
-        g, ParsaConfig(k=k, backend="device_scan", block_size=64,
-                       use_kernel=False, seed=3))
-    assert np.array_equal(got, want.parts_u)
-
-    got = once(lambda: blocked_partition_u_hostloop(
-        g, k, block=64, use_kernel=False, seed=3))
-    want = get_backend("host_blocked_oracle")(
-        g, ParsaConfig(k=k, backend="host_blocked_oracle", block_size=64,
-                       use_kernel=False, seed=3))
-    assert np.array_equal(got, want.parts_u)
+    parts, sets = sequential_parsa_impl(g, 8, b=8, a=4, seed=1)
+    return parts, sets, None
 
 
-# ---------------------------------------------- legacy shims: exact parity
-# Acceptance: each shim, now delegating through the backend registry, returns
-# results bit-identical to its pre-refactor implementation on a fixed-seed
-# 2k-vertex graph.
-def test_parity_partition_u(parity_graph):
-    from repro.core.partition_u import partition_u, partition_u_impl
+def _parallel_sim_impl(g):
+    from repro.core.parallel import parallel_parsa_impl
 
-    res = partition_u(parity_graph, 8, seed=3)
-    ref = partition_u_impl(parity_graph, 8, seed=3)
-    assert np.array_equal(res.parts_u, ref.parts_u)
-    assert np.array_equal(res.neighbor_sets, ref.neighbor_sets)
+    rep, s_masks = parallel_parsa_impl(g, 8, b=8, a=2, workers=4, tau=2,
+                                       seed=5)
+    return rep.parts_u, s_masks, (rep.pushed_bytes, rep.pulled_bytes,
+                                  rep.tasks, rep.stale_pushes_missed)
 
 
-def test_parity_sequential_parsa(parity_graph):
-    from repro.core.subgraphs import sequential_parsa, sequential_parsa_impl
+def _device_scan_impl(g):
+    from repro.core.jax_partition import blocked_partition_u_impl
 
-    got = sequential_parsa(parity_graph, 8, b=8, a=4, seed=1)
-    want, _ = sequential_parsa_impl(parity_graph, 8, b=8, a=4, seed=1)
-    assert np.array_equal(got, want)
-
-
-def test_parity_parallel_parsa(parity_graph):
-    from repro.core.parallel import ParallelParsa, parallel_parsa_impl
-
-    rep = ParallelParsa(8, workers=4, tau=2, seed=5).run(parity_graph, b=8, a=2)
-    ref, _ = parallel_parsa_impl(parity_graph, 8, b=8, a=2, workers=4, tau=2,
-                                 seed=5)
-    assert np.array_equal(rep.parts_u, ref.parts_u)
-    assert rep.pushed_bytes == ref.pushed_bytes
-    assert rep.pulled_bytes == ref.pulled_bytes
-    assert rep.tasks == ref.tasks
-    assert rep.stale_pushes_missed == ref.stale_pushes_missed
+    parts, s_masks = blocked_partition_u_impl(g, 8, block=256,
+                                              use_kernel=False, seed=7)
+    return parts, s_masks, None
 
 
-def test_parity_blocked_partition_u(parity_graph):
-    from repro.core.jax_partition import (
-        blocked_partition_u, blocked_partition_u_impl)
+def _host_blocked_oracle_impl(g):
+    from repro.core.jax_partition import blocked_partition_u_hostloop_impl
 
-    got = blocked_partition_u(parity_graph, 8, block=256, use_kernel=False,
-                              seed=7)
-    want, _ = blocked_partition_u_impl(parity_graph, 8, block=256,
-                                       use_kernel=False, seed=7)
-    assert np.array_equal(got, want)
+    parts, s_masks = blocked_partition_u_hostloop_impl(
+        g, 8, block=256, use_kernel=False, seed=7)
+    return parts, s_masks, None
 
 
-def test_parity_blocked_partition_u_hostloop(parity_graph):
-    from repro.core.jax_partition import (
-        blocked_partition_u_hostloop, blocked_partition_u_hostloop_impl)
-
-    got = blocked_partition_u_hostloop(parity_graph, 8, block=256,
-                                       use_kernel=False, seed=7)
-    want, _ = blocked_partition_u_hostloop_impl(parity_graph, 8, block=256,
-                                                use_kernel=False, seed=7)
-    assert np.array_equal(got, want)
+@pytest.mark.parametrize("cfg,impl", [
+    (dict(backend="host", seed=3), _host_impl),
+    (dict(backend="host", blocks=8, init_iters=4, seed=1), _host_blocks_impl),
+    (dict(backend="parallel_sim", workers=4, tau=2, blocks=8, init_iters=2,
+          seed=5), _parallel_sim_impl),
+    (dict(backend="device_scan", block_size=256, use_kernel=False, seed=7),
+     _device_scan_impl),
+    (dict(backend="host_blocked_oracle", block_size=256, use_kernel=False,
+          seed=7), _host_blocked_oracle_impl),
+], ids=["host", "host_blocks", "parallel_sim", "device_scan",
+        "host_blocked_oracle"])
+def test_registry_matches_impl(parity_graph, cfg, impl):
+    res = partition(parity_graph, ParsaConfig(k=8, refine_v=False, **cfg))
+    parts, sets, traffic = impl(parity_graph)
+    assert np.array_equal(res.parts_u, parts)
+    got_sets = res.neighbor_sets if sets.dtype == bool else res.s_masks
+    assert np.array_equal(got_sets, sets)
+    if traffic is not None:
+        t = res.traffic
+        assert (t.pushed_bytes, t.pulled_bytes, t.tasks,
+                t.stale_pushes_missed) == traffic
 
 
 def test_parity_build_placement_matches_pre_refactor_recipe(parity_graph):

@@ -8,19 +8,20 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.api import ParsaConfig
+from repro.api_backends import get_backend
 from repro.core.bipartite import from_edges
 from repro.core.jax_partition import (
     _assign_block,
     _assign_block_rounds,
     _compact_block_rounds,
     _dense_block_rounds,
-    blocked_partition_u,
-    blocked_partition_u_hostloop,
+    _launch_parallel_scan,
+    _place_parallel_blocks,
     dispatch_counter,
     pack_graph_blocks,
     parallel_blocked_partition_u_impl,
     reset_dispatch_counts,
-    shard_parsa_step,
 )
 from repro.graphs import text_like
 from repro.kernels.parsa_cost import (
@@ -37,6 +38,16 @@ from repro.kernels.parsa_cost import (
     parsa_select_ref,
     unpack_bitmask,
 )
+
+
+def _blocked(g, k, backend="device_scan", *, block, init_sets=None, **kw):
+    """(parts_u, final packed s_masks) of a registered block backend
+    (``device_scan`` or ``host_blocked_oracle``), reached through the
+    registry as ``repro.api.partition`` reaches it, with refine off."""
+    cfg = ParsaConfig(k=k, backend=backend, block_size=block,
+                      refine_v=False, **kw)
+    out = get_backend(backend)(g, cfg, init_sets=init_sets)
+    return out.parts_u, out.s_masks
 
 
 def _random_graph(seed, nu=None, nv=None, ne=None):
@@ -231,30 +242,28 @@ def test_scan_pipeline_matches_hostloop(seed, k, block):
     catch-up round), and the last block is ragged."""
     g = _random_graph(seed)
     assert not pack_graph_blocks(g, block).trunc.any()
-    want, s_want = blocked_partition_u_hostloop(
-        g, k, block=block, use_kernel=False, seed=seed, return_sets=True)
-    got, s_got = blocked_partition_u(g, k, block=block, use_kernel=False,
-                                     seed=seed, return_sets=True)
+    want, s_want = _blocked(g, k, "host_blocked_oracle", block=block,
+                            use_kernel=False, seed=seed)
+    got, s_got = _blocked(g, k, block=block, use_kernel=False, seed=seed)
     assert np.array_equal(got, want)
     assert np.array_equal(s_got, s_want)
 
 
 def test_scan_pipeline_matches_hostloop_kernel_path():
     g = text_like(500, 800, mean_len=20, seed=9)
-    want = blocked_partition_u_hostloop(g, 8, block=128, use_kernel=False,
-                                        seed=0)
-    got = blocked_partition_u(g, 8, block=128, use_kernel=True,
-                              interpret=True, seed=0)
+    want, _ = _blocked(g, 8, "host_blocked_oracle", block=128,
+                       use_kernel=False, seed=0)
+    got, _ = _blocked(g, 8, block=128, use_kernel=True, interpret=True,
+                      seed=0)
     assert np.array_equal(got, want)
 
 
 def test_scan_pipeline_matches_hostloop_trunc_fallback():
     """cap small enough that the dense fallbacks actually run."""
     g = text_like(400, 600, mean_len=25, seed=5)
-    want = blocked_partition_u_hostloop(g, 4, block=128, use_kernel=False,
-                                        seed=0)
-    got = blocked_partition_u(g, 4, block=128, use_kernel=False, seed=0,
-                              cap=3)
+    want, _ = _blocked(g, 4, "host_blocked_oracle", block=128,
+                       use_kernel=False, seed=0)
+    got, _ = _blocked(g, 4, block=128, use_kernel=False, seed=0, cap=3)
     assert np.array_equal(got, want)
 
 
@@ -279,11 +288,11 @@ def test_scan_pipeline_matches_hostloop_mixed_blocks(backend):
     has_trunc = pack_graph_blocks(g, block, order=order,
                                   cap=cap).trunc.any(axis=1)
     assert has_trunc.any() and not has_trunc.all()
-    want, s_want = blocked_partition_u_hostloop(
-        g, k, block=block, use_kernel=False, seed=seed, return_sets=True)
+    want, s_want = _blocked(g, k, "host_blocked_oracle", block=block,
+                            use_kernel=False, seed=seed)
     if backend == "device_scan":
-        got, s_got = blocked_partition_u(g, k, block=block, use_kernel=False,
-                                         seed=seed, cap=cap, return_sets=True)
+        got, s_got = _blocked(g, k, block=block, use_kernel=False,
+                              seed=seed, cap=cap)
     else:
         got, s_got, _ = parallel_blocked_partition_u_impl(
             g, k, workers=1, block=block, seed=seed, cap=cap)
@@ -369,10 +378,10 @@ def test_scan_pipeline_matches_hostloop_init_sets():
     g = text_like(300, 500, mean_len=15, seed=6)
     rng = np.random.default_rng(1)
     S0 = rng.random((8, g.num_v)) < 0.1
-    want = blocked_partition_u_hostloop(g, 8, block=128, init_sets=S0,
-                                        use_kernel=False, seed=2)
-    got = blocked_partition_u(g, 8, block=128, init_sets=S0,
-                              use_kernel=False, seed=2)
+    want, _ = _blocked(g, 8, "host_blocked_oracle", block=128, init_sets=S0,
+                       use_kernel=False, seed=2)
+    got, _ = _blocked(g, 8, block=128, init_sets=S0, use_kernel=False,
+                      seed=2)
     assert np.array_equal(got, want)
 
 
@@ -385,8 +394,7 @@ def test_blocked_partition_returns_final_s_masks():
 
     g = text_like(350, 500, mean_len=15, seed=11)
     k = 8
-    parts, s_masks = blocked_partition_u(g, k, block=128, use_kernel=False,
-                                         seed=3, return_sets=True)
+    parts, s_masks = _blocked(g, k, block=128, use_kernel=False, seed=3)
     assert s_masks.shape == (k, (g.num_v + 31) // 32)
     dense = unpack_bitmask(s_masks, g.num_v)
     assert np.array_equal(dense, need_matrix(g, parts, k))  # cold start
@@ -403,26 +411,24 @@ def test_init_sets_round_trip_host_device_parity():
     g2 = text_like(250, 500, mean_len=15, seed=13)
     k = 8
     # device run on g1 → packed sets → dense view
-    _, s_masks = blocked_partition_u(g1, k, block=128, use_kernel=False,
-                                     seed=0, return_sets=True)
+    _, s_masks = _blocked(g1, k, block=128, use_kernel=False, seed=0)
     S0 = unpack_bitmask(s_masks, g1.num_v)
     # the SAME dense sets warm-start both paths on g2 → identical parts
-    want = blocked_partition_u_hostloop(g2, k, block=128, init_sets=S0,
-                                        use_kernel=False, seed=2)
-    got, s2 = blocked_partition_u(g2, k, block=128, init_sets=S0,
-                                  use_kernel=False, seed=2, return_sets=True)
+    want, _ = _blocked(g2, k, "host_blocked_oracle", block=128,
+                       init_sets=S0, use_kernel=False, seed=2)
+    got, s2 = _blocked(g2, k, block=128, init_sets=S0, use_kernel=False,
+                       seed=2)
     assert np.array_equal(got, want)
     # and the device's final sets re-pack what the host loop accumulated
-    _, s2_host = blocked_partition_u_hostloop(
-        g2, k, block=128, init_sets=S0, use_kernel=False, seed=2,
-        return_sets=True)
+    _, s2_host = _blocked(g2, k, "host_blocked_oracle", block=128,
+                          init_sets=S0, use_kernel=False, seed=2)
     assert np.array_equal(s2, s2_host)
 
 
 def test_blocked_partition_balance_and_cover():
     g = text_like(777, 700, mean_len=18, seed=3)
     k = 8
-    parts = blocked_partition_u(g, k, block=128, use_kernel=False)
+    parts, _ = _blocked(g, k, block=128, use_kernel=False)
     assert np.all(parts >= 0) and np.all(parts < k)
     sizes = np.bincount(parts, minlength=k)
     assert sizes.max() - sizes.min() <= 1
@@ -451,7 +457,7 @@ def test_single_dispatch_per_call(monkeypatch):
     for g in (small, large):
         calls.clear()
         with dispatch_counter() as counts:
-            blocked_partition_u(g, 4, block=128, use_kernel=False)
+            _blocked(g, 4, block=128, use_kernel=False)
         assert calls == [1]  # one scan launch, independent of n_blocks
         assert counts["partition_scan"] == 1
 
@@ -461,10 +467,10 @@ def test_dispatch_counter_isolated():
     nesting observes only launches inside each scope."""
     g = text_like(120, 200, mean_len=8, seed=1)
     with dispatch_counter() as outer:
-        blocked_partition_u(g, 2, block=64, use_kernel=False)
+        _blocked(g, 2, block=64, use_kernel=False)
         with dispatch_counter() as inner:
             assert inner["partition_scan"] == 0  # fresh scope
-            blocked_partition_u(g, 2, block=64, use_kernel=False)
+            _blocked(g, 2, block=64, use_kernel=False)
         assert inner["partition_scan"] == 1
         assert outer["partition_scan"] == 2
         reset_dispatch_counts()
@@ -476,7 +482,7 @@ def test_dispatch_counter_isolated():
     with dispatch_counter() as outer2:
         with dispatch_counter():
             pass  # both counters are {"partition_scan": 0} here
-        blocked_partition_u(g, 2, block=64, use_kernel=False)
+        _blocked(g, 2, block=64, use_kernel=False)
         assert outer2["partition_scan"] == 1
 
 
@@ -624,62 +630,35 @@ print("PARALLEL_DEVICE_SMOKE_OK")
     assert "PARALLEL_DEVICE_SMOKE_OK" in out.stdout, out.stdout + out.stderr
 
 
-# ------------------------------------------------------------- shard_parsa
-def test_shard_parsa_step_single_device():
-    """One Alg-4 round through shard_map on a 1-wide data axis."""
-    from jax.sharding import Mesh, PartitionSpec as P
-
-    g = text_like(256, 400, mean_len=12, seed=8)
-    k, block = 4, 64
-    packed = pack_graph_blocks(g, block)
-    body = shard_parsa_step(k, axis="data", use_kernel=False)
-    mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
+# ------------------------------------------- Alg 4 core: padded block stack
+@pytest.mark.parametrize("merge_every", [1, 2, 3])
+def test_parallel_device_padded_stack(merge_every):
+    """The Alg 4 core the stream runs, on a ragged last block and (at
+    ``merge_every=2``) one whole padding block from ``_pad_block_stack``:
+    padding rows read -1 and leak into neither sizes nor S."""
+    g = text_like(150, 300, mean_len=10, seed=3)  # 150 % 64 != 0 → ragged
+    k = 4
+    packed = pack_graph_blocks(g, 64)
+    assert packed.valid.shape[0] == 3
+    blocks = _place_parallel_blocks(packed, workers=1,
+                                    merge_every=merge_every)
+    assert blocks.nb_per == (4 if merge_every == 2 else 3)
     W = (g.num_v + 31) // 32
-    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(),) * 8,
-                       out_specs=(P(), P(), P()), check_vma=False)
-    parts, merged, sizes = fn(
-        jnp.asarray(packed.valid), jnp.asarray(packed.widx),
-        jnp.asarray(packed.vals), jnp.asarray(packed.trunc),
-        jnp.asarray(packed.overflow_spans),
-        jnp.asarray(packed.overflow_words),
-        jnp.zeros((k, W), jnp.int32), jnp.zeros((k,), jnp.int32))
-    parts = np.asarray(parts).reshape(-1)[: g.num_u]
-    assert (parts >= 0).all()
-    sizes_np = np.bincount(parts, minlength=k)
-    assert sizes_np.max() - sizes_np.min() <= 1
-    assert np.array_equal(np.asarray(sizes), sizes_np)
-    # merged S_i == union of assigned vertices' neighborhoods
-    want = np.zeros((k, W), np.uint32)
-    for local, u in enumerate(packed.order):
-        i = parts[local]
-        nb = pack_bitmask([g.neighbors(int(u))], g.num_v).view(np.uint32)[0]
-        want[i] |= nb
-    assert np.array_equal(np.asarray(merged).view(np.uint32), want)
-
-
-@pytest.mark.parametrize("select", ["rounds", "seq"])
-def test_shard_parsa_step_padded_blocks(select):
-    """Ragged U-shards: padding rows must not leak into sizes or S."""
-    from jax.sharding import Mesh, PartitionSpec as P
-
-    g = text_like(150, 300, mean_len=10, seed=3)  # 150 % 64 != 0 → padding
-    k, block = 4, 64
-    packed = pack_graph_blocks(g, block)
-    body = shard_parsa_step(k, axis="data", use_kernel=False, select=select)
-    mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
-    W = (g.num_v + 31) // 32
-    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(),) * 8,
-                       out_specs=(P(), P(), P()), check_vma=False)
-    parts, merged, sizes = fn(
-        jnp.asarray(packed.valid), jnp.asarray(packed.widx),
-        jnp.asarray(packed.vals), jnp.asarray(packed.trunc),
-        jnp.asarray(packed.overflow_spans),
-        jnp.asarray(packed.overflow_words),
-        jnp.zeros((k, W), jnp.int32), jnp.zeros((k,), jnp.int32))
-    parts = np.asarray(parts).reshape(-1)
+    parts_blocks, s_out, sizes, _ = _launch_parallel_scan(
+        blocks, jnp.zeros((k, W), jnp.int32), jnp.zeros((k,), jnp.int32),
+        k=k, use_kernel=False, interpret=None)
+    parts = blocks.in_stack_order(parts_blocks)
     real, pad = parts[: g.num_u], parts[g.num_u:]
+    assert pad.size == blocks.nb_per * 64 - g.num_u
     assert (real >= 0).all() and (pad == -1).all()
     # sizes count exactly the real vertices — no phantom picks
-    assert int(np.asarray(sizes).sum()) == g.num_u
-    assert np.array_equal(np.asarray(sizes),
-                          np.bincount(real, minlength=k))
+    sizes = np.asarray(sizes)
+    assert np.array_equal(sizes, np.bincount(real, minlength=k))
+    assert int(sizes.sum()) == g.num_u
+    assert sizes.max() - sizes.min() <= 1
+    # the sets are the union of each assigned row's neighbourhood
+    want = np.zeros((k, W), np.uint32)
+    for local, u in enumerate(packed.order):
+        nb = pack_bitmask([g.neighbors(int(u))], g.num_v).view(np.uint32)[0]
+        want[real[local]] |= nb
+    assert np.array_equal(np.asarray(s_out).view(np.uint32), want)

@@ -3,10 +3,8 @@ blocked/bitmask reformulation (DESIGN §2)."""
 import numpy as np
 import pytest
 
-from repro.core import (
-    ParallelParsa, evaluate, global_initialization, partition_v, random_parts,
-)
-from repro.core.jax_partition import blocked_partition_u
+from repro.api import ParsaConfig, partition
+from repro.core import evaluate, global_initialization, partition_v, random_parts
 from repro.graphs import text_like
 
 
@@ -14,28 +12,36 @@ def _quality(g, parts_u, k):
     return evaluate(g, parts_u, partition_v(g, parts_u, k), k).traffic_max
 
 
+def _parallel_sim(g, k, b, init_sets=None, **kw):
+    """The Alg 4 parameter-server simulation over ``b`` subgraphs, through
+    the facade with refine off."""
+    return partition(g, ParsaConfig(k=k, backend="parallel_sim", blocks=b,
+                                    refine_v=False, **kw),
+                     init_sets=init_sets)
+
+
 def test_parallel_matches_sequential_quality(small_text_graph):
     g, k = small_text_graph, 8
-    seq = ParallelParsa(k, workers=1, tau=0).run(g, b=8)
-    par = ParallelParsa(k, workers=4, tau=2).run(g, b=8)
+    seq = _parallel_sim(g, k, 8, workers=1, tau=0)
+    par = _parallel_sim(g, k, 8, workers=4, tau=2)
     q_seq, q_par = _quality(g, seq.parts_u, k), _quality(g, par.parts_u, k)
     # §5.4: staleness costs at most a few percent (allow 25% on tiny graphs)
     assert q_par <= q_seq * 1.25
-    assert par.stale_pushes_missed > 0  # staleness actually exercised
+    assert par.traffic.stale_pushes_missed > 0  # staleness actually exercised
 
 
 def test_eventual_consistency_still_beats_random(small_text_graph):
     g, k = small_text_graph, 8
-    par = ParallelParsa(k, workers=8, tau=None).run(g, b=16)
+    par = _parallel_sim(g, k, 16, workers=8, tau=None)
     rand = _quality(g, random_parts(g.num_u, k, 0), k)
     assert _quality(g, par.parts_u, k) < rand
 
 
 def test_global_initialization_helps(small_ctr_graph):
     g, k = small_ctr_graph, 8
-    cold = ParallelParsa(k, workers=4, tau=1, seed=1).run(g, b=8)
+    cold = _parallel_sim(g, k, 8, workers=4, tau=1, seed=1)
     S0 = global_initialization(g, k, sample_frac=0.1, seed=1)
-    warm = ParallelParsa(k, workers=4, tau=1, seed=1).run(g, b=8, init_sets=S0)
+    warm = _parallel_sim(g, k, 8, init_sets=S0, workers=4, tau=1, seed=1)
     assert _quality(g, warm.parts_u, k) <= _quality(g, cold.parts_u, k) * 1.1
 
 
@@ -43,7 +49,6 @@ def test_parallel_sim_w1_tau0_equals_host_backend(small_text_graph):
     """Degenerate parity: one worker with no delay is the §4.2 sequential
     subgraph stream — bit-identical parts and (packed) sets vs the host
     backend at the same block count."""
-    from repro.api import ParsaConfig, partition
     from repro.core.parallel import parallel_parsa_impl
     from repro.kernels.parsa_cost import pack_bitmask
 
@@ -113,7 +118,9 @@ def test_parallel_sim_peak_memory_bounded():
 def test_blocked_jax_partitioner(small_text_graph):
     """TPU-native blocked greedy: balanced, complete, beats random."""
     g, k = small_text_graph, 8
-    parts = blocked_partition_u(g, k, block=128)
+    parts = partition(g, ParsaConfig(
+        k=k, backend="device_scan", block_size=128, use_kernel=True,
+        refine_v=False)).parts_u
     assert np.all(parts >= 0)
     sizes = np.bincount(parts, minlength=k)
     assert sizes.max() - sizes.min() <= 1

@@ -5,10 +5,11 @@ import pytest
 pytest.importorskip("hypothesis", reason="property tests need hypothesis")
 from hypothesis import given, settings, strategies as st  # noqa: E402
 
+from repro.api import ParsaConfig, partition
 from repro.core import (
-    evaluate, from_edges, need_matrix, partition_u, partition_v, random_parts,
-    sequential_parsa,
+    evaluate, from_edges, need_matrix, partition_v, random_parts,
 )
+from repro.core.partition_u import partition_u_impl
 from repro.graphs import text_like
 
 
@@ -27,7 +28,7 @@ def bipartite_graphs(draw):
 @given(g=bipartite_graphs(), k=st.integers(2, 8))
 @settings(max_examples=30, deadline=None)
 def test_partition_u_invariants(g, k):
-    res = partition_u(g, k)
+    res = partition_u_impl(g, k)
     # disjoint cover
     assert res.parts_u.shape == (g.num_u,)
     assert np.all(res.parts_u >= 0) and np.all(res.parts_u < k)
@@ -41,7 +42,7 @@ def test_partition_u_invariants(g, k):
 @given(g=bipartite_graphs(), k=st.integers(2, 8))
 @settings(max_examples=30, deadline=None)
 def test_partition_v_invariants(g, k):
-    parts_u = partition_u(g, k).parts_u
+    parts_u = partition_u_impl(g, k).parts_u
     need = need_matrix(g, parts_u, k)
     parts_v = partition_v(g, parts_u, k)
     for j in range(g.num_v):
@@ -56,7 +57,7 @@ def test_partition_v_invariants(g, k):
 @settings(max_examples=20, deadline=None)
 def test_repeated_sweeps_never_worse(g, k):
     """§3.2: repeated sweeps improve until convergence (convex ⇒ global)."""
-    parts_u = partition_u(g, k).parts_u
+    parts_u = partition_u_impl(g, k).parts_u
     m1 = evaluate(g, parts_u, partition_v(g, parts_u, k, sweeps=1), k)
     m3 = evaluate(g, parts_u, partition_v(g, parts_u, k, sweeps=3), k)
     assert m3.traffic_max <= m1.traffic_max
@@ -65,7 +66,7 @@ def test_repeated_sweeps_never_worse(g, k):
 def test_cost_definition_matches_bruteforce():
     g = text_like(60, 150, mean_len=10, seed=3)
     k = 4
-    parts_u = partition_u(g, k).parts_u
+    parts_u = partition_u_impl(g, k).parts_u
     parts_v = partition_v(g, parts_u, k)
     m = evaluate(g, parts_u, parts_v, k)
     # brute force with python sets
@@ -83,7 +84,8 @@ def test_cost_definition_matches_bruteforce():
 def test_parsa_beats_random_on_traffic(small_text_graph, small_ctr_graph):
     k = 8
     for g in (small_text_graph, small_ctr_graph):
-        pu = sequential_parsa(g, k, b=4, a=2)
+        pu = partition(g, ParsaConfig(k=k, backend="host", blocks=4,
+                                      init_iters=2, refine_v=False)).parts_u
         pv = partition_v(g, pu, k)
         m = evaluate(g, pu, pv, k)
         mr = evaluate(g, random_parts(g.num_u, k, 0), random_parts(g.num_v, k, 1), k)
@@ -95,8 +97,8 @@ def test_init_sets_carry_over():
     """Incremental partitioning: warm S_i must change (and not hurt) results."""
     g = text_like(200, 500, mean_len=15, seed=5)
     k = 4
-    r1 = partition_u(g, k)
-    r2 = partition_u(g, k, init_sets=r1.neighbor_sets)
+    r1 = partition_u_impl(g, k)
+    r2 = partition_u_impl(g, k, init_sets=r1.neighbor_sets)
     assert np.array_equal(
         r2.neighbor_sets & ~r1.neighbor_sets,
         need_matrix(g, r2.parts_u, k) & ~r1.neighbor_sets)

@@ -10,7 +10,8 @@ import sys
 import numpy as np
 import pytest
 
-from repro.core import evaluate, partition_v, random_parts, sequential_parsa
+from repro.api import ParsaConfig, partition
+from repro.core import evaluate, partition_v, random_parts
 from repro.core.placement import build_placement
 from repro.graphs import ctr_like
 from repro.ml import DBPGConfig, PSCluster, make_problem
@@ -40,7 +41,8 @@ def test_partition_quality_objectives_jointly():
     """All three §2.4 objectives beat random simultaneously (Table 2 shape)."""
     g = ctr_like(600, 2000, nnz_per_row=18, seed=5)
     k = 16
-    pu = sequential_parsa(g, k, b=4, a=4)
+    pu = partition(g, ParsaConfig(k=k, backend="host", blocks=4,
+                                  init_iters=4, refine_v=False)).parts_u
     pv = partition_v(g, pu, k, sweeps=2)
     m = evaluate(g, pu, pv, k)
     mr = evaluate(g, random_parts(g.num_u, k, 0), random_parts(g.num_v, k, 1), k)
