@@ -15,13 +15,15 @@ Dispatch model (one scan, donated carries, fused select)
 The pipeline is fully device-resident:
 
 1. *Packing* — the whole permuted U is packed host-side in one vectorized
-   sorted pass over the edge array (``pack_bitmask_csr_sparse``; zero
-   Python-level per-vertex work) into per-row *compact word lists* of the
-   first ``cap`` nonzero words, plus a flat list of the *overflow words*
-   of the rows that have more (row, word, value entries, with a span per
-   block).  No dense ``(…, W)`` array exists on the host, and none is
-   copied to the device.  On the jnp path a block with no truncated row
-   is greedy-assigned from its word lists alone; the kernel path, and a
+   pass over the edge array in its own row order (``pack_row_words``;
+   zero Python-level per-vertex work; a sort only when some row's
+   columns arrive out of order) into per-row *compact word lists* of the
+   first ``cap`` nonzero words, moved as whole rows into the permuted
+   block layout, plus a flat list of the *overflow words* of the rows
+   that have more (row, word, value entries, with a span per block).
+   No dense ``(…, W)`` array exists on the host, and none is copied to
+   the device.  On the jnp path a block with no truncated row is
+   greedy-assigned from its word lists alone; the kernel path, and a
    jnp-path block that holds a truncated row, rebuild the block's (B, W)
    bitmask inside the scan by a 12K-element scatter-add plus its
    overflow span (``_rebuild_nbr``).
@@ -82,7 +84,7 @@ from ..kernels.parsa_cost import (
     BIG,
     coerce_packed_sets,
     pack_bitmask,
-    pack_bitmask_csr_sparse,
+    pack_row_words,
     parsa_cost,
     parsa_cost_select,
     select_greedy_from_cost,
@@ -211,16 +213,21 @@ class PackedBlocks(NamedTuple):
     overflow_words: np.ndarray  # (3, L) int32 rows: block-local row, word
                                 #   index, word value; L a power of two
     order: np.ndarray     # (num_u,) int64 — global vertex id per packed row
+    sorted: bool          # every row's words arrived ascending: no sort ran
+
+
+# the per-block arrays ``_partition_scan`` takes, in its argument order
+_SCAN_BLOCK_FIELDS = ("valid", "widx", "vals", "trunc", "overflow_spans",
+                      "overflow_words")
 
 
 def _upload_blocks(packed: PackedBlocks) -> tuple[jax.Array, ...]:
-    """The six per-block arrays ``_partition_scan`` takes, in its argument
-    order, each put on the default device by ``jnp.asarray``: the one
-    place the scan's block layout is spelled out for a one-chip call
-    (``_place_parallel_blocks`` places the same six on a worker mesh)."""
-    return tuple(jnp.asarray(x) for x in (
-        packed.valid, packed.widx, packed.vals, packed.trunc,
-        packed.overflow_spans, packed.overflow_words))
+    """The six per-block arrays ``_partition_scan`` takes
+    (``_SCAN_BLOCK_FIELDS``), each put on the default device by
+    ``jnp.asarray``: the one place the scan's block layout is spelled out
+    for a one-chip call (``_place_parallel_blocks`` places the same six on
+    a worker mesh)."""
+    return tuple(jnp.asarray(getattr(packed, f)) for f in _SCAN_BLOCK_FIELDS)
 
 
 def _overflow_slots(words: int, floor: int, slots: int) -> int:
@@ -244,11 +251,14 @@ def pack_graph_blocks(
 ) -> PackedBlocks:
     """Pack all of U (in ``order``) into padded (n_blocks, B, …) stacks.
 
-    Fully vectorized: one CSR gather + one sorted pass over the edge array
-    yields the compact word lists and, from the same pass, the overflow
-    words of the truncated rows (each row's nonzero words past its first
-    ``cap``).  No per-vertex Python work, and no dense (…, W) array on the
-    host.
+    Fully vectorized (``pack_row_words``): one pass over the edge array
+    in the graph's own row order yields each row's compact word list and
+    the overflow words of the truncated rows (each row's nonzero words
+    past its first ``cap``), and ``order`` moves whole rows into the block
+    layout; no edge is gathered into permuted order.  Rows whose columns
+    arrive in ascending word order need no sort; a graph with a row that
+    does not is sorted by (row, word) first, to the same output.  No
+    per-vertex Python work, and no dense (…, W) array on the host.
 
     The overflow list holds ``_overflow_slots(words, floor, min_slots)``
     entries, ``floor`` = ``tb_pad · cap · n_blocks`` (as many words as
@@ -258,41 +268,37 @@ def pack_graph_blocks(
     stability: streaming feeds re-pack same-sized chunks whose overflow
     count jitters with the data, and a capacity that holds (``min_slots``,
     the session's high-water mark) keeps every feed on the
-    already-compiled scan.
+    already-compiled scan.  ``sorted`` says whether the pass ran without
+    its sort.
     """
     n = graph.num_u
     if order is None:
         order = np.arange(n, dtype=np.int64)
     order = np.asarray(order, dtype=np.int64)
-    uniq, wordvals, widx, vals, trunc, _, W, pos = pack_bitmask_csr_sparse(
-        graph.u_indptr, graph.u_indices, graph.num_v, rows=order, cap=cap)
     n_blocks = max(1, -(-n // block))
-    pad = n_blocks * block - n
-    if pad:
-        widx = np.pad(widx, [(0, pad), (0, 0)])
-        vals = np.pad(vals, [(0, pad), (0, 0)])
-        trunc = np.pad(trunc, [(0, pad)])
+    rw = pack_row_words(graph.u_indptr, graph.u_indices, cap, rows=order,
+                        n_out=n_blocks * block)
     valid = (np.arange(n_blocks * block) < n).reshape(n_blocks, block)
-    # overflow words: sorted by (row, word), so each block's are contiguous
-    over = np.flatnonzero(pos >= cap)
-    rows, words = np.divmod(uniq[over], W)
+    # overflow words: in (row, word) order, so each block's are contiguous
+    words = rw.over_rows.size
     floor = 0 if tb_pad is None else tb_pad * cap * n_blocks
-    L = _overflow_slots(over.size, floor, min_slots)
+    L = _overflow_slots(words, floor, min_slots)
     overflow_words = np.zeros((3, L), np.int32)
-    overflow_words[0, :over.size] = rows % block
-    overflow_words[1, :over.size] = words
-    overflow_words[2, :over.size] = wordvals[over]
-    stops = np.cumsum(np.bincount(rows // block, minlength=n_blocks))
+    overflow_words[0, :words] = rw.over_rows % block
+    overflow_words[1, :words] = rw.over_words
+    overflow_words[2, :words] = rw.over_vals
+    stops = np.cumsum(np.bincount(rw.over_rows // block, minlength=n_blocks))
     overflow_spans = np.stack(
         [np.concatenate([[0], stops[:-1]]), stops], axis=1).astype(np.int32)
     return PackedBlocks(
         valid=valid,
-        widx=widx.reshape(n_blocks, block, cap),
-        vals=vals.reshape(n_blocks, block, cap),
-        trunc=trunc.reshape(n_blocks, block),
+        widx=rw.widx.reshape(n_blocks, block, cap),
+        vals=rw.vals.reshape(n_blocks, block, cap),
+        trunc=rw.trunc.reshape(n_blocks, block),
         overflow_spans=overflow_spans,
         overflow_words=overflow_words,
         order=order,
+        sorted=rw.sorted,
     )
 
 

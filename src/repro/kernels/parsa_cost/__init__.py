@@ -5,7 +5,7 @@ from .ops import (  # noqa: F401
     pack_bitmask,
     pack_bitmask_csr,
     pack_bitmask_csr_compact,
-    pack_bitmask_csr_sparse,
+    pack_row_words,
     packed_delta,
     packed_intersect_counts,
     packed_union,

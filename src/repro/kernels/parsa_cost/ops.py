@@ -2,6 +2,8 @@
 (padding + dispatch) and the host-side bitmask packing routines."""
 from __future__ import annotations
 
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -251,57 +253,108 @@ def pack_bitmask_csr(
     return out.reshape(n, W).view(np.int32)
 
 
-def pack_bitmask_csr_sparse(
+class RowWords(NamedTuple):
+    """Compact word lists of packed rows and the overflow words of the rows
+    that have more than ``cap`` (``pack_row_words``)."""
+
+    widx: np.ndarray        # (n_out, cap) int32 nonzero-word indices
+    vals: np.ndarray        # (n_out, cap) int32 word values at widx
+    trunc: np.ndarray       # (n_out,) bool — row has > cap nonzero words
+    over_rows: np.ndarray   # (L,) int64 output row of each overflow word
+    over_words: np.ndarray  # (L,) int32 its word index
+    over_vals: np.ndarray   # (L,) int32 its word value
+    sorted: bool            # every row's words arrived ascending: no sort
+
+
+def pack_row_words(
     indptr: np.ndarray,
     indices: np.ndarray,
-    num_v: int,
+    cap: int,
     rows: np.ndarray | None = None,
-    cap: int = 48,
-) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray, np.ndarray, int, int]:
-    """Sparse fused packing: the bitmask as (distinct flat word index, word
-    value) pairs plus per-row compact word lists, in one sorted pass.
+    n_out: int | None = None,
+) -> RowWords:
+    """Per-row compact word lists of a CSR matrix, in one pass over its
+    edges in their own order, with the rows moved whole to where ``rows``
+    puts them.
 
-    One argsort over (row, word) keys yields both representations without
-    ever touching a dense (n, W) array — the caller chooses where (and
-    whether) to densify: ``pack_bitmask_csr_compact`` scatters on the host,
-    while the device scan (``pack_graph_blocks``) never densifies globally
-    at all — it ships only the compact lists plus the truncated rows'
-    overflow words (the entries at ``pos >= cap``) and rebuilds a block's
-    (B, W) bitmask on device inside the scan where it needs one.
+    Output row i holds CSR row ``rows[i]`` (``rows`` a permutation of the
+    CSR rows; ``None`` keeps their order): its first ``cap`` distinct
+    nonzero words in ascending order in ``widx``/``vals``, ``(0, 0)`` in
+    the slots past its last, and ``trunc[i]`` set when it has more.  The
+    words past cap are the overflow entries (output row, word, value),
+    in (output row, word) order.  Rows ``n .. n_out - 1`` are all-zero
+    padding.  No dense (n, W) array is built.
 
-    Returns (uniq (nnz,) int64 flat indices into the (n, W) mask,
-    wordvals (nnz,) int32, widx (n, cap) int32, vals (n, cap) int32,
-    truncated (n,) bool, n, W, pos (nnz,) int64 — each entry's rank among
-    its row's nonzero words).
+    Each edge's word and bit come from its column in CSR order, and the
+    distinct words of a row are the runs of equal words in it, OR-reduced.
+    That needs each row's words in ascending order, which the generators
+    and ``from_edges`` give; ``sorted`` says so, and only when some row's
+    do not are the edges first sorted by (row, word) within the CSR.
+    The permutation is applied to whole rows: the entries of CSR row r
+    are written from ``dest_row[r] · cap`` on, ``dest_row`` the inverse
+    of ``rows``; only the overflow entries are sorted, by output row.
     """
-    n, _, row_ids, cols = _gather_row_cols(indptr, indices, rows)
-    W = (num_v + 31) // 32
-    widx = np.zeros((n, cap), dtype=np.int32)
-    vals = np.zeros((n, cap), dtype=np.uint32)
-    if cols.size == 0:
-        return (np.zeros(0, np.int64), np.zeros(0, np.int32), widx,
-                vals.view(np.int32), np.zeros(n, bool), n, W,
-                np.zeros(0, np.int64))
-    fw = row_ids * W + (cols >> 5)            # flat (row, word) key per edge
-    bit = (np.int64(1) << (cols & 31)).astype(np.uint32)
-    srt = np.argsort(fw, kind="stable")
-    fs, bs = fw[srt], bit[srt]
-    boundary = np.empty(fs.size, bool)
+    indptr = np.asarray(indptr, dtype=np.int64)
+    indices = np.asarray(indices)[indptr[0]:indptr[-1]]
+    indptr = indptr - indptr[0]
+    n = indptr.shape[0] - 1
+    n_out = n if n_out is None else n_out
+    widx = np.zeros((n_out, cap), dtype=np.int32)
+    vals = np.zeros((n_out, cap), dtype=np.int32)
+    trunc = np.zeros(n_out, dtype=bool)
+    no_overflow = (np.zeros(0, np.int64), np.zeros(0, np.int32),
+                   np.zeros(0, np.int32))
+    nnz = indices.shape[0]
+    if nnz == 0:
+        return RowWords(widx, vals, trunc, *no_overflow, True)
+    word = (indices >> 5).astype(np.int32, copy=False)
+    bit = np.left_shift(np.int32(1), (indices & 31).astype(np.int32,
+                                                           copy=False))
+    lens = np.diff(indptr)
+    starts = indptr[:-1][lens > 0]          # first edge of each nonempty row
+    inner = starts[1:] - 1                  # edges whose successor is a start
+    descent = word[1:] < word[:-1]
+    descent[inner] = False
+    ascending = not descent.any()
+    if not ascending:                       # sort within rows only
+        srt = np.lexsort((word, np.repeat(np.arange(n), lens)))
+        word, bit = word[srt], bit[srt]
+    boundary = np.empty(nnz, bool)          # a row's next distinct word
     boundary[0] = True
-    np.not_equal(fs[1:], fs[:-1], out=boundary[1:])
+    np.not_equal(word[1:], word[:-1], out=boundary[1:])
+    boundary[starts] = True
     first = np.flatnonzero(boundary)
-    uniq = fs[first]                          # distinct (row, word), sorted
-    acc = np.bitwise_or.reduceat(bs, first)   # the word values
-    r = uniq // W
-    counts = np.bincount(r, minlength=n)
-    starts = np.concatenate([[0], np.cumsum(counts)[:-1]])
-    pos = np.arange(uniq.size, dtype=np.int64) - starts[r]
-    keep = pos < cap
-    flat = r[keep] * cap + pos[keep]
-    widx.reshape(-1)[flat] = (uniq[keep] % W).astype(np.int32)
-    vals.reshape(-1)[flat] = acc[keep]
-    return (uniq, acc.view(np.int32), widx, vals.view(np.int32),
-            counts > cap, n, W, pos)
+    uword = word[first]
+    uval = np.bitwise_or.reduceat(bit, first)
+    estart = np.searchsorted(first, indptr)  # each row's first entry
+    counts = np.diff(estart)
+    if rows is None:
+        dest_row = np.arange(n, dtype=np.int64)
+    else:
+        rows = np.asarray(rows, dtype=np.int64)
+        dest_row = np.full(n, -1, np.int64)
+        if rows.shape == (n,):
+            dest_row[rows] = np.arange(n)
+        if (dest_row < 0).any():
+            raise ValueError(f"rows is not a permutation of the {n} CSR rows")
+    # entry e of CSR row r goes to dest_row[r] · cap + (e - estart[r])
+    dest = np.arange(first.size, dtype=np.int64) + np.repeat(
+        dest_row * cap - estart[:-1], counts)
+    over_cap = counts > cap
+    trunc[dest_row] = over_cap
+    if not over_cap.any():
+        widx.reshape(-1)[dest] = uword
+        vals.reshape(-1)[dest] = uval
+        return RowWords(widx, vals, trunc, *no_overflow, ascending)
+    erow = np.repeat(np.arange(n), counts)
+    keep = dest - dest_row[erow] * cap < cap
+    widx.reshape(-1)[dest[keep]] = uword[keep]
+    vals.reshape(-1)[dest[keep]] = uval[keep]
+    over = np.flatnonzero(~keep)
+    over_rows = dest_row[erow[over]]
+    srt = np.argsort(over_rows, kind="stable")  # a row's words stay ascending
+    return RowWords(widx, vals, trunc, over_rows[srt], uword[over][srt],
+                    uval[over][srt], ascending)
 
 
 def pack_bitmask_csr_compact(
@@ -311,16 +364,16 @@ def pack_bitmask_csr_compact(
     rows: np.ndarray | None = None,
     cap: int = 48,
 ) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
-    """Fused ``pack_bitmask_csr`` + ``compact_row_words`` in one sorted pass.
+    """``pack_bitmask_csr`` and the compact word lists of
+    ``pack_row_words`` (``rows`` a permutation of the CSR rows).
 
     Returns (masks (n, W) int32, widx (n, cap) int32, vals (n, cap) int32,
-    truncated (n,) bool), matching the two-step reference exactly.
+    truncated (n,) bool), matching ``pack_bitmask_csr`` +
+    ``compact_row_words`` exactly.
     """
-    uniq, wordvals, widx, vals, trunc, n, W, _ = pack_bitmask_csr_sparse(
-        indptr, indices, num_v, rows=rows, cap=cap)
-    masks = np.zeros(n * W, dtype=np.int32)
-    masks[uniq] = wordvals
-    return masks.reshape(n, W), widx, vals, trunc
+    rw = pack_row_words(indptr, indices, cap, rows=rows)
+    masks = pack_bitmask_csr(indptr, indices, num_v, rows=rows)
+    return masks, rw.widx, rw.vals, rw.trunc
 
 
 def compact_row_words(

@@ -50,6 +50,7 @@ from ..api_backends import TrafficCounters
 from ..core.bipartite import BipartiteGraph
 from ..core.costs import PartitionMetrics
 from ..core.jax_partition import (
+    _SCAN_BLOCK_FIELDS,
     _count_dispatch,
     _launch_parallel_scan,
     _parallel_traffic,
@@ -147,19 +148,21 @@ _PHASE_SUMS = ("partition_u", "total")
 
 def _packed_counters(packed, grew: bool) -> dict[str, int]:
     """Per-feed counts of the packed blocks a scan is given: the bytes of
-    the arrays put on the device (all but ``order``, which stays on the
-    host); the truncated rows, the overflow words they carry and the
-    overflow list's capacity; whether this feed raised that capacity (a
-    new shape: the scan compiles); and the blocks with no truncated row,
-    which the jnp scan runs in compact space."""
+    the arrays put on the device (``_SCAN_BLOCK_FIELDS``; ``order`` stays
+    on the host); the truncated rows, the overflow words they carry and
+    the overflow list's capacity; whether this feed raised that capacity
+    (a new shape: the scan compiles); the blocks with no truncated row,
+    which the jnp scan runs in compact space; and whether the chunk's rows
+    arrived in ascending word order, so the pack ran without its sort."""
     spans = packed.overflow_spans
-    return {"upload_bytes": (sum(int(x.nbytes) for x in packed)
-                             - int(packed.order.nbytes)),
+    return {"upload_bytes": sum(int(getattr(packed, f).nbytes)
+                                for f in _SCAN_BLOCK_FIELDS),
             "channel_rows": int(packed.trunc.sum()),
             "channel_words": int((spans[:, 1] - spans[:, 0]).sum()),
             "channel_slots": int(packed.overflow_words.shape[1]),
             "channel_grew": int(grew),
-            "compact_blocks": int((~packed.trunc.any(axis=1)).sum())}
+            "compact_blocks": int((~packed.trunc.any(axis=1)).sum()),
+            "pack_sorted": int(packed.sorted)}
 
 
 def _merge_counters(blocks, traffic: TrafficCounters, k: int,

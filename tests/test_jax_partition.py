@@ -10,7 +10,7 @@ import pytest
 
 from repro.api import ParsaConfig
 from repro.api_backends import get_backend
-from repro.core.bipartite import from_edges
+from repro.core.bipartite import BipartiteGraph, from_edges
 from repro.core.jax_partition import (
     _assign_block,
     _assign_block_rounds,
@@ -30,6 +30,7 @@ from repro.kernels.parsa_cost import (
     pack_bitmask,
     pack_bitmask_csr,
     pack_bitmask_csr_compact,
+    pack_row_words,
     packed_delta,
     packed_union,
     packed_union_delta,
@@ -129,6 +130,130 @@ def test_pack_graph_blocks_shapes_and_trunc_side_channel():
     assert packed.trunc[blk, rows].all()
     assert np.array_equal(
         np.unique(blk * 256 + rows), np.flatnonzero(packed.trunc.ravel()))
+
+
+def _layout_graph(layout, seed, n=613, nv=4000):
+    """A graph whose rows hold their columns as ``layout`` says:
+    ``ascending`` (distinct, sorted), ``repeated`` (sorted, with repeats),
+    ``shuffled`` (distinct, in random order), ``shuffled_repeated`` or
+    ``empty`` (nine rows in ten empty).  Rows run from empty to hubs of
+    600 columns (up to 150 words), so every cap in the tests truncates."""
+    rng = np.random.default_rng(seed)
+    lens = rng.choice([0, 1, 3, 12, 40, 120, 600], n,
+                      p=[.1, .1, .2, .3, .15, .1, .05])
+    if layout == "empty":
+        lens[rng.random(n) < 0.9] = 0
+    rows = []
+    for m in lens:
+        cols = rng.integers(0, nv, m)
+        if layout in ("ascending", "shuffled", "empty"):
+            cols = np.unique(cols)
+        if layout.startswith("shuffled"):
+            cols = rng.permutation(cols)
+        else:
+            cols = np.sort(cols)
+        rows.append(cols)
+    indptr = np.zeros(n + 1, np.int64)
+    np.cumsum([len(c) for c in rows], out=indptr[1:])
+    return BipartiteGraph(n, nv, indptr,
+                          np.concatenate(rows).astype(np.int32))
+
+
+def _pack_reference(g, block, order, cap, tb_pad, min_slots):
+    """``PackedBlocks`` from the dense packing: ``pack_bitmask_csr`` rows
+    in ``order``, ``compact_row_words`` for the word lists, and each
+    truncated row's nonzero words past its first ``cap`` read off its
+    dense row, in (row, word) order."""
+    from repro.core.jax_partition import _overflow_slots
+
+    n = g.num_u
+    nb = max(1, -(-n // block))
+    dense = pack_bitmask_csr(g.u_indptr, g.u_indices, g.num_v, rows=order)
+    widx, vals, trunc = compact_row_words(dense, cap)
+    entries = [(i % block, w, dense[i, w])
+               for i in np.flatnonzero(trunc)
+               for w in np.flatnonzero(dense[i])[cap:]]
+    floor = 0 if tb_pad is None else tb_pad * cap * nb
+    words = np.zeros((3, _overflow_slots(len(entries), floor, min_slots)),
+                     np.int32)
+    if entries:
+        words[:, :len(entries)] = np.array(entries, np.int64).T
+    per_block = np.bincount(
+        [i // block for i in np.flatnonzero(trunc)
+         for _ in np.flatnonzero(dense[i])[cap:]], minlength=nb)
+    stops = np.cumsum(per_block)
+    pad = nb * block - n
+
+    def rows_of(a):
+        return np.pad(a, [(0, pad)] + [(0, 0)] * (a.ndim - 1))
+
+    return dict(
+        valid=(np.arange(nb * block) < n).reshape(nb, block),
+        widx=rows_of(widx).reshape(nb, block, cap),
+        vals=rows_of(vals).reshape(nb, block, cap),
+        trunc=rows_of(trunc).reshape(nb, block),
+        overflow_spans=np.stack([stops - per_block, stops],
+                                axis=1).astype(np.int32),
+        overflow_words=words,
+        order=np.asarray(order, np.int64))
+
+
+@pytest.mark.parametrize("layout,cap,permute,tb_pad,min_slots", [
+    ("ascending", 2, True, None, 0),
+    ("ascending", 4, False, 8, 0),
+    ("ascending", 48, True, 4, 1 << 12),
+    ("repeated", 4, True, 2, 0),
+    ("repeated", 48, False, None, 0),
+    ("shuffled", 2, True, 8, 0),
+    ("shuffled", 4, True, None, 1 << 10),
+    ("shuffled", 48, False, None, 0),
+    ("shuffled_repeated", 4, True, 8, 1 << 14),
+    ("shuffled_repeated", 48, True, None, 0),
+    ("empty", 2, True, None, 0),
+    ("empty", 48, True, 4, 0),
+])
+def test_pack_graph_blocks_matches_dense_packing(layout, cap, permute,
+                                                 tb_pad, min_slots):
+    """Property: every ``PackedBlocks`` array — values, dtype and shape —
+    equals the one built from the dense packing, whether the rows arrive
+    ascending (no sort) or not (sorted within rows first), with empty rows,
+    rows past cap, a permuted order, a ragged last block, and an overflow
+    capacity set by ``tb_pad`` or ``min_slots``."""
+    g = _layout_graph(layout, seed=cap)
+    block = 64
+    assert g.num_u % block
+    order = (np.random.default_rng(9).permutation(g.num_u) if permute
+             else np.arange(g.num_u))
+    packed = pack_graph_blocks(g, block, order=order if permute else None,
+                               cap=cap, tb_pad=tb_pad, min_slots=min_slots)
+    want = _pack_reference(g, block, order, cap, tb_pad, min_slots)
+    for name, arr in want.items():
+        got = getattr(packed, name)
+        assert got.dtype == arr.dtype and got.shape == arr.shape, name
+        assert np.array_equal(got, arr), name
+    assert packed.trunc.any()
+    assert packed.sorted == (not layout.startswith("shuffled"))
+
+
+@pytest.mark.parametrize("rows", [[0, 0, 1], [0, 1], [2, 0, 1, 1]])
+def test_pack_row_words_rejects_a_non_permutation(rows):
+    """Rows are moved whole by the inverse of ``rows``, so a repeat or a
+    missing row is refused rather than packed wrong."""
+    indptr, indices = np.array([0, 2, 3, 5]), np.array([1, 40, 3, 70, 2])
+    with pytest.raises(ValueError, match="permutation"):
+        pack_row_words(indptr, indices, 2, rows=np.array(rows))
+
+
+def test_pack_graph_blocks_of_a_graph_with_no_edges():
+    g = BipartiteGraph(70, 500, np.zeros(71, np.int64),
+                       np.zeros(0, np.int32))
+    packed = pack_graph_blocks(g, 64, cap=4, tb_pad=2)
+    want = _pack_reference(g, 64, np.arange(70), 4, 2, 0)
+    for name, arr in want.items():
+        got = getattr(packed, name)
+        assert got.dtype == arr.dtype and got.shape == arr.shape, name
+        assert np.array_equal(got, arr), name
+    assert packed.sorted
 
 
 @pytest.mark.parametrize("cap", [4, 48])

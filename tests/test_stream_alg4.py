@@ -118,7 +118,8 @@ def test_alg4_merge_counters_match_formulas_and_traffic(alg4):
         assert f["tasks"] == workers * rounds
         # the packed blocks' counts are there as on one chip
         assert {"upload_bytes", "channel_rows", "channel_slots",
-                "compact_blocks"} <= set(c)
+                "compact_blocks", "pack_sorted"} <= set(c)
+        assert c["pack_sorted"] == 1     # the chunks' rows arrive sorted
 
 
 def test_alg4_phases_and_merge_counters_in_the_trace(alg4):
@@ -130,8 +131,8 @@ def test_alg4_phases_and_merge_counters_in_the_trace(alg4):
     assert {m: spans["parsa.feed.wait"][m] for m in merge} == {
         m: last["counters"][m] for m in merge}
     assert not set(merge) & set(spans["parsa.feed.pack"])
-    assert spans["parsa.feed.pack"]["compact_blocks"] == last["counters"][
-        "compact_blocks"]
+    for name in ("compact_blocks", "pack_sorted"):
+        assert spans["parsa.feed.pack"][name] == last["counters"][name]
 
 
 def test_alg4_worker_shards_sit_on_their_own_devices(alg4):

@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 from repro.api import ParsaConfig, ParsaStreamConfig, StreamSession
+from repro.core.bipartite import BipartiteGraph
 from repro.core.jax_partition import pack_graph_blocks
 from repro.graphs import ctr_like_stream, text_like
 
@@ -102,6 +103,28 @@ def test_feed_counters_count_compact_blocks():
         blocks = -(-ch.num_u // 64)
         assert upd.counters["channel_rows"] == 0
         assert upd.counters["compact_blocks"] == blocks
+
+
+@pytest.mark.parametrize("reverse", [False, True])
+def test_feed_counters_count_pack_sorted(reverse):
+    """``pack_sorted`` reads 1 on a chunk whose rows hold their columns in
+    ascending order (the pack runs without its sort) and 0 on the same
+    chunk with every row's columns reversed (it sorts), and the feed's
+    other counters are the same either way."""
+    g = text_like(600, 3000, mean_len=20, seed=2)
+    if reverse:
+        lens = np.diff(g.u_indptr)
+        start = np.repeat(g.u_indptr[:-1], lens)
+        stop = np.repeat(g.u_indptr[1:], lens)
+        e = np.arange(g.u_indices.size)
+        g = BipartiteGraph(g.num_u, g.num_v, g.u_indptr,
+                           g.u_indices[start + stop - 1 - e])
+    upd = _session(g.num_v, repartition="never", tb_pad=4).feed(g)
+    assert upd.counters["pack_sorted"] == int(not reverse)
+    ref = _session(g.num_v, repartition="never", tb_pad=4).feed(
+        text_like(600, 3000, mean_len=20, seed=2))
+    assert {k: v for k, v in upd.counters.items() if k != "pack_sorted"} \
+        == {k: v for k, v in ref.counters.items() if k != "pack_sorted"}
 
 
 def test_feed_spans_land_in_the_profiler_trace(tmp_path):
