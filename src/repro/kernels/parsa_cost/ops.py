@@ -290,9 +290,9 @@ def pack_row_words(
     That needs each row's words in ascending order, which the generators
     and ``from_edges`` give; ``sorted`` says so, and only when some row's
     do not are the edges first sorted by (row, word) within the CSR.
-    The permutation is applied to whole rows: the entries of CSR row r
-    are written from ``dest_row[r] · cap`` on, ``dest_row`` the inverse
-    of ``rows``; only the overflow entries are sorted, by output row.
+    The word lists are laid out in CSR row order and ``rows`` then moves
+    them as whole rows; no edge is gathered into permuted order.  Only the
+    overflow entries are sorted, by output row.
     """
     indptr = np.asarray(indptr, dtype=np.int64)
     indices = np.asarray(indices)[indptr[0]:indptr[-1]]
@@ -325,36 +325,37 @@ def pack_row_words(
     boundary[starts] = True
     first = np.flatnonzero(boundary)
     uword = word[first]
-    uval = np.bitwise_or.reduceat(bit, first)
+    uval = bit[first]
+    rest = np.flatnonzero(~boundary)        # a run's later edges: few
+    np.bitwise_or.at(uval, np.searchsorted(first, rest, "right") - 1,
+                     bit[rest])
     estart = np.searchsorted(first, indptr)  # each row's first entry
     counts = np.diff(estart)
-    if rows is None:
-        dest_row = np.arange(n, dtype=np.int64)
-    else:
-        rows = np.asarray(rows, dtype=np.int64)
-        dest_row = np.full(n, -1, np.int64)
-        if rows.shape == (n,):
-            dest_row[rows] = np.arange(n)
-        if (dest_row < 0).any():
-            raise ValueError(f"rows is not a permutation of the {n} CSR rows")
-    # entry e of CSR row r goes to dest_row[r] · cap + (e - estart[r])
-    dest = np.arange(first.size, dtype=np.int64) + np.repeat(
-        dest_row * cap - estart[:-1], counts)
+    rows = np.arange(n) if rows is None else np.asarray(rows, np.int64)
+    dest_row = np.full(n, -1, np.int64)      # output row of each CSR row
+    if rows.shape == (n,):
+        dest_row[rows] = np.arange(n)
+    if (dest_row < 0).any():
+        raise ValueError(f"rows is not a permutation of the {n} CSR rows")
     over_cap = counts > cap
-    trunc[dest_row] = over_cap
-    if not over_cap.any():
-        widx.reshape(-1)[dest] = uword
-        vals.reshape(-1)[dest] = uval
-        return RowWords(widx, vals, trunc, *no_overflow, ascending)
-    erow = np.repeat(np.arange(n), counts)
-    keep = dest - dest_row[erow] * cap < cap
-    widx.reshape(-1)[dest[keep]] = uword[keep]
-    vals.reshape(-1)[dest[keep]] = uval[keep]
-    over = np.flatnonzero(~keep)
-    over_rows = dest_row[erow[over]]
-    srt = np.argsort(over_rows, kind="stable")  # a row's words stay ascending
-    return RowWords(widx, vals, trunc, over_rows[srt], uword[over][srt],
-                    uval[over][srt], ascending)
+    overflow = no_overflow
+    if over_cap.any():                       # the entries past a row's cap
+        erow = np.repeat(np.arange(n), counts)
+        keep = np.arange(first.size) - estart[erow] < cap
+        over = np.flatnonzero(~keep)
+        over_rows = dest_row[erow[over]]
+        srt = np.argsort(over_rows, kind="stable")  # a row's words stay ascending
+        overflow = (over_rows[srt], uword[over][srt], uval[over][srt])
+        uword, uval = uword[keep], uval[keep]
+    # a CSR row's kept entries fill its first slots, in CSR row order; then
+    # whole rows move to where ``rows`` puts them
+    slot = np.arange(cap) < np.minimum(counts, cap)[:, None]
+    for out, entries in ((widx, uword), (vals, uval)):
+        by_csr_row = np.zeros((n, cap), np.int32)
+        by_csr_row[slot] = entries
+        np.take(by_csr_row, rows, axis=0, out=out[:n], mode="clip")
+    trunc[:n] = over_cap[rows]
+    return RowWords(widx, vals, trunc, *overflow, ascending)
 
 
 def pack_bitmask_csr_compact(

@@ -17,6 +17,7 @@ from repro.core.jax_partition import (
     _compact_block_rounds,
     _dense_block_rounds,
     _launch_parallel_scan,
+    _overflow_slots,
     _place_parallel_blocks,
     dispatch_counter,
     pack_graph_blocks,
@@ -164,8 +165,6 @@ def _pack_reference(g, block, order, cap, tb_pad, min_slots):
     in ``order``, ``compact_row_words`` for the word lists, and each
     truncated row's nonzero words past its first ``cap`` read off its
     dense row, in (row, word) order."""
-    from repro.core.jax_partition import _overflow_slots
-
     n = g.num_u
     nb = max(1, -(-n // block))
     dense = pack_bitmask_csr(g.u_indptr, g.u_indices, g.num_v, rows=order)
@@ -198,6 +197,13 @@ def _pack_reference(g, block, order, cap, tb_pad, min_slots):
         order=np.asarray(order, np.int64))
 
 
+def _assert_packed_equal(packed, want):
+    for name, arr in want.items():
+        got = getattr(packed, name)
+        assert got.dtype == arr.dtype and got.shape == arr.shape, name
+        assert np.array_equal(got, arr), name
+
+
 @pytest.mark.parametrize("layout,cap,permute,tb_pad,min_slots", [
     ("ascending", 2, True, None, 0),
     ("ascending", 4, False, 8, 0),
@@ -227,10 +233,7 @@ def test_pack_graph_blocks_matches_dense_packing(layout, cap, permute,
     packed = pack_graph_blocks(g, block, order=order if permute else None,
                                cap=cap, tb_pad=tb_pad, min_slots=min_slots)
     want = _pack_reference(g, block, order, cap, tb_pad, min_slots)
-    for name, arr in want.items():
-        got = getattr(packed, name)
-        assert got.dtype == arr.dtype and got.shape == arr.shape, name
-        assert np.array_equal(got, arr), name
+    _assert_packed_equal(packed, want)
     assert packed.trunc.any()
     assert packed.sorted == (not layout.startswith("shuffled"))
 
@@ -249,10 +252,7 @@ def test_pack_graph_blocks_of_a_graph_with_no_edges():
                        np.zeros(0, np.int32))
     packed = pack_graph_blocks(g, 64, cap=4, tb_pad=2)
     want = _pack_reference(g, 64, np.arange(70), 4, 2, 0)
-    for name, arr in want.items():
-        got = getattr(packed, name)
-        assert got.dtype == arr.dtype and got.shape == arr.shape, name
-        assert np.array_equal(got, arr), name
+    _assert_packed_equal(packed, want)
     assert packed.sorted
 
 
